@@ -110,8 +110,21 @@ def audit_enabled() -> bool:
 def audit_jit_enabled() -> bool:
     """SIMTPU_AUDIT_JIT=0 forces the pure-numpy reference path for the
     bulk checks (the `SIMTPU_NATIVE=0` pattern: same verdicts, pinned by
-    tests, for debugging and hosts where jit is unwanted)."""
-    return os.environ.get("SIMTPU_AUDIT_JIT", "1") != "0"
+    tests, for debugging and hosts where jit is unwanted).  The jit runs
+    on the host CPU backend (`_bulk_flags_jax`), so a JAX without one
+    (JAX_PLATFORMS naming only an accelerator) takes the twin too; the
+    report's `mode` says which ran."""
+    return os.environ.get("SIMTPU_AUDIT_JIT", "1") != "0" and _host_cpu() is not None
+
+
+def _host_cpu():
+    """JAX's host CPU device, or None when JAX_PLATFORMS leaves it out."""
+    import jax
+
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
 
 
 @dataclass
@@ -486,37 +499,44 @@ def _get_bulk_jit():
     return _bulk_jit
 
 
-def _bulk_flags_jax(tensors, e: _Entries, node_valid: np.ndarray) -> np.ndarray:
-    from jax.experimental import enable_x64
-
+def _bulk_jit_args(tensors, e: _Entries, node_valid: np.ndarray) -> tuple:
+    """The bulk jit's host arguments, f64 where the audit accumulates."""
     ext = tensors.ext
-    fn = _get_bulk_jit()
+    return (
+        np.asarray(tensors.alloc, np.float64),
+        np.asarray(tensors.static_mask, bool),
+        np.asarray(tensors.vol_mask, bool),
+        np.asarray(node_valid, bool),
+        np.asarray(tensors.ports, bool),
+        np.asarray(tensors.vol_rw, bool),
+        np.asarray(tensors.vol_ro, bool),
+        np.asarray(tensors.vol_att, bool),
+        np.asarray(tensors.vol_class_mask, np.float64),
+        np.asarray(tensors.attach_limits, np.float64),
+        (ext.vg_cap - ext.vg_req0).astype(np.float64),
+        np.asarray((ext.sdev_cap > 0) & ~ext.sdev_alloc0, bool),
+        ext.gpu_dev_total.astype(np.float64),
+        e.g.astype(np.int64),
+        e.n.astype(np.int64),
+        e.req,
+        e.forced,
+        e.pin.astype(np.int64),
+        e.lvm,
+        e.sdev,
+        e.gpu,
+    )
+
+
+def _bulk_flags_jax(tensors, e: _Entries, node_valid: np.ndarray) -> np.ndarray:
+    import jax
+
     # x64 at trace time: the audit accumulates prefixes in f64 (like the
-    # numpy twin) — verdict parity between the modes is a pinned contract
-    with enable_x64():
-        flags = fn(
-            np.asarray(tensors.alloc, np.float64),
-            np.asarray(tensors.static_mask, bool),
-            np.asarray(tensors.vol_mask, bool),
-            np.asarray(node_valid, bool),
-            np.asarray(tensors.ports, bool),
-            np.asarray(tensors.vol_rw, bool),
-            np.asarray(tensors.vol_ro, bool),
-            np.asarray(tensors.vol_att, bool),
-            np.asarray(tensors.vol_class_mask, np.float64),
-            np.asarray(tensors.attach_limits, np.float64),
-            (ext.vg_cap - ext.vg_req0).astype(np.float64),
-            np.asarray((ext.sdev_cap > 0) & ~ext.sdev_alloc0, bool),
-            ext.gpu_dev_total.astype(np.float64),
-            e.g.astype(np.int64),
-            e.n.astype(np.int64),
-            e.req,
-            e.forced,
-            e.pin.astype(np.int64),
-            e.lvm,
-            e.sdev,
-            e.gpu,
-        )
+    # numpy twin) — verdict parity between the modes is a pinned contract.
+    # The program runs on the host CPU whatever the engines ran on: the
+    # v5e compiler crashes (SIGSEGV) on it past ~16k placed pods, and the
+    # audit is the check of the device's answer, not more device work
+    with jax.enable_x64(True), jax.default_device(_host_cpu()):
+        flags = _get_bulk_jit()(*_bulk_jit_args(tensors, e, node_valid))
     return np.asarray(flags).astype(np.int64)
 
 

@@ -16,10 +16,16 @@ on every run, so a cold `simtpu apply` finds the whole probe sweep's
 round/scan bodies already in this cache instead of compiling
 per-candidate-size specializations the previous process never produced.
 
-Enabled by default for the CLI, the bench, and the test suite. Knobs:
+Enabled by default for the CLI, the bench and `chip_smoke.py`. Where the
+cache lives:
 
-- ``SIMTPU_COMPILATION_CACHE``: cache directory; ``0``/``off`` disables.
-  Default ``~/.cache/simtpu/xla``.
+- ``JAX_COMPILATION_CACHE_DIR`` set: that directory, which JAX reads
+  itself; nothing here overrides it.
+- otherwise one fixed path inside the checkout, ``<repo>/.jax_cache``
+  (git-ignored). The path is part of the cache key, so it never depends
+  on the home directory, a temp name, a pid or the time.
+- ``SIMTPU_COMPILATION_CACHE=0``/``off`` disables the cache (`make
+  bench-cold` measures the cold path that way).
 - cache entries are written for every compilation taking >= 0.5 s (the
   engine's scan/round bodies all cost seconds to compile; tiny dispatches
   stay out of the cache).
@@ -41,8 +47,9 @@ import os
 import sys
 
 _DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "simtpu", "xla"
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
 )
+_OFF_VALUES = ("0", "off", "false", "none", "no", "disabled")
 
 
 def _skip_note(reason: str) -> None:
@@ -52,39 +59,44 @@ def _skip_note(reason: str) -> None:
     print(f"simtpu: persistent compilation cache off ({reason})", file=sys.stderr)
 
 
-def enable_compilation_cache(path: str = None) -> str | None:
-    """Point JAX's persistent compilation cache at `path` (default:
-    $SIMTPU_COMPILATION_CACHE or ~/.cache/simtpu/xla). Returns the cache
-    directory, or None when disabled — via SIMTPU_COMPILATION_CACHE=0/off
-    or because the backend is CPU (see module docstring); every disabled
-    exit says so on stderr."""
+def enable_compilation_cache() -> str | None:
+    """Turn JAX's persistent compilation cache on (see the module docstring
+    for where it lives). Returns the cache directory, or None when
+    disabled — via SIMTPU_COMPILATION_CACHE=0/off or because the backend
+    is CPU; every disabled exit says so on stderr."""
     import jax
 
     env = os.environ.get("SIMTPU_COMPILATION_CACHE", "")
-    if env.lower() in ("0", "off", "false", "none", "no", "disabled"):
+    if env.lower() in _OFF_VALUES:
         _skip_note(f"SIMTPU_COMPILATION_CACHE={env}")
         return None
+    outside_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
     try:
         if jax.default_backend() == "cpu":
             # ACCELERATOR ONLY — the XLA:CPU deserialize segfault (module
-            # docstring); the note keeps the gating observable
+            # docstring); an outside JAX_COMPILATION_CACHE_DIR would arm
+            # JAX's own cache, so the refusal switches it off explicitly
+            if outside_dir:
+                jax.config.update("jax_enable_compilation_cache", False)
             _skip_note("CPU backend: the XLA:CPU executable loader "
                        "segfaults on cache deserialization")
             return None
-    except Exception as exc:
-        _skip_note(f"backend probe failed: {type(exc).__name__}")
+    except RuntimeError as exc:
+        _skip_note(f"backend probe failed: {exc}")
         return None
-    cache_dir = path or env or _DEFAULT_DIR
+    cache_dir = outside_dir or _DEFAULT_DIR
     try:
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
         # cache regardless of executable size (the default also caches
         # everything; pinned for stability across jax versions)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # the dir flag LAST: it alone activates the cache, so a partial
-        # failure above leaves the cache fully off and the None return honest
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception as exc:  # cache is an optimization — never fail the run
-        _skip_note(f"setup failed: {type(exc).__name__}: {exc}")
+        if not outside_dir:
+            # the dir flag LAST: it alone activates the cache, so a partial
+            # failure above leaves the cache fully off and the None return
+            # honest
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+    except OSError as exc:  # cache is an optimization — never fail the run
+        _skip_note(f"setup failed: {exc}")
         return None
     return cache_dir

@@ -57,6 +57,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs.metrics import REGISTRY
 from ..obs.trace import span
 
 log = logging.getLogger("simtpu.precompile")
@@ -214,6 +215,9 @@ class AotPipeline:
                 first = not job.warned
                 job.warned = True
                 self._failures += 1
+            # process-wide: a compile the device's compiler refused must
+            # stay visible after the pipeline that hid it is gone
+            REGISTRY.counter("aot.failures").inc()
             if first:
                 log.warning(
                     "AOT precompile of %r failed (%s: %s); falling back to "

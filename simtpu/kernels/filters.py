@@ -13,6 +13,7 @@ kernels here are the ones that depend on mutable scan state.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # Relative slack for float32 resource comparisons; the reference compares exact
@@ -70,8 +71,11 @@ def attach_limits_ok(
     """
     present = (vols_any > 0).astype(jnp.float32)  # [N, W]
     cm = class_mask.astype(jnp.float32)  # [C, W]
-    used = present @ cm.T  # [N, C] unique volumes on node per class
-    new = ((1.0 - present) * want_att.astype(jnp.float32)[None, :]) @ cm.T
+    hp = jax.lax.Precision.HIGHEST  # volume counts must stay exact
+    used = jnp.matmul(present, cm.T, precision=hp)  # [N, C] unique volumes per class
+    new = jnp.matmul(
+        (1.0 - present) * want_att.astype(jnp.float32)[None, :], cm.T, precision=hp
+    )
     return jnp.all((new == 0) | (used + new <= limits), axis=-1)
 
 

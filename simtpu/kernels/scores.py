@@ -13,9 +13,14 @@ the full node axis but normalized over the feasible mask only, exactly like
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..core.tensorize import RES_CPU, RES_MEMORY
+
+# pod counts are integers in f32: contract them exactly (the TPU's default
+# precision rounds operands to bf16, exact only up to 256)
+_HP = jax.lax.Precision.HIGHEST
 
 MAX_NODE_SCORE = 100.0
 
@@ -114,7 +119,7 @@ def topology_spread_score(
     registry weight 2 applied by the caller): lower matching count in the
     node's domains → higher score, inverse-min-max to [0, 100]; nodes missing
     a topology key count 0 for that constraint."""
-    return spread_score_from_raw(soft_w @ cnt_at, mask)
+    return spread_score_from_raw(jnp.matmul(soft_w, cnt_at, precision=_HP), mask)
 
 
 def selector_spread_compose(
@@ -172,8 +177,8 @@ def selector_spread_score(
     spread pods of the same service/controller across nodes, then zones with
     zoneWeighting=2/3 when zones exist."""
     return selector_spread_from_counts(
-        ss_host.astype(jnp.float32) @ cnt_at,
-        ss_zone.astype(jnp.float32) @ cnt_at,
+        jnp.matmul(ss_host.astype(jnp.float32), cnt_at, precision=_HP),
+        jnp.matmul(ss_zone.astype(jnp.float32), cnt_at, precision=_HP),
         jnp.any(ss_zone),
         mask,
     )

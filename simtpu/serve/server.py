@@ -357,10 +357,15 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _handle(self, app: SimtpuServer, method: str, parts) -> None:
         if method == "GET" and parts == ("healthz",):
+            import jax
+
             self._send(200, {
                 "ok": True,
                 "uptime_s": round(app.uptime_s, 3),
                 "schema_version": SCHEMA_VERSION,
+                # the backend this daemon computes on: a load generator
+                # labels its record with it, never with its own guess
+                "platform": jax.default_backend(),
             })
             return
         if method == "GET" and parts == ("readyz",):

@@ -222,6 +222,10 @@ def _relax_kernel(iters, feas, req, cnt, fixed, cap, valid_s, lr):
     has demand but no feasible valid node — unsatisfiable outright)."""
     count_trace("solve")  # trace-time only: once per shape bucket
 
+    # f32 on every backend: the TPU's default precision would contract
+    # in bf16 passes and move the residual the verdict thresholds read
+    hp = jax.lax.Precision.HIGHEST
+
     def one(valid):
         f = feas & valid[None, :]
         free = jnp.maximum((cap - fixed) * valid[:, None], 0.0)
@@ -230,13 +234,13 @@ def _relax_kernel(iters, feas, req, cnt, fixed, cap, valid_s, lr):
         y0 = jnp.where(f, (cnt / jnp.maximum(nfeas, 1))[:, None], 0.0)
 
         def body(_, y):
-            load = jnp.einsum("cn,cr->nr", y, req)
+            load = jnp.einsum("cn,cr->nr", y, req, precision=hp)
             over = jnp.maximum(load - free, 0.0)
-            grad = jnp.einsum("nr,cr->cn", over, req)
+            grad = jnp.einsum("nr,cr->cn", over, req, precision=hp)
             return _project_rows(y - lr * grad, cnt, f)
 
         y = jax.lax.fori_loop(0, iters, body, y0)
-        load = jnp.einsum("cn,cr->nr", y, req)
+        load = jnp.einsum("cn,cr->nr", y, req, precision=hp)
         over = jnp.maximum(load - free, 0.0)
         residual = jnp.where(stuck, jnp.inf, jnp.max(over, initial=0.0))
         return y, residual

@@ -65,8 +65,12 @@ class TestSpanTracer:
         """Concurrent spans from worker threads lose no events and keep
         per-thread nesting depths (the AOT pool regime)."""
         n_threads, per_thread = 8, 50
+        # every worker is alive at once (idents are reused only after a
+        # thread exits), so each records under its own ident
+        start = threading.Barrier(n_threads)
 
         def work():
+            start.wait(timeout=30)
             for _ in range(per_thread):
                 with obs_trace.span("t.outer"):
                     with obs_trace.span("t.inner"):
@@ -76,15 +80,13 @@ class TestSpanTracer:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
         evs = obs_trace.events()
         assert len(evs) == n_threads * per_thread * 2
         for name, _, _, _, depth, _ in evs:
             assert depth == (1 if name == "t.inner" else 0)
-        # at least two distinct recording threads (idents are REUSED when
-        # a thread exits before a later one starts, so == n_threads would
-        # be flaky by scheduler luck)
-        assert len({tid for _, _, _, tid, _, _ in evs}) >= 2
+        assert len({tid for _, _, _, tid, _, _ in evs}) == n_threads
 
     def test_aot_pool_compile_spans(self, tracer):
         """The precompile pipeline's per-signature compile spans are

@@ -9,6 +9,7 @@ import numpy as np
 
 from simtpu.core.objects import ResourceTypes, set_label
 from simtpu.core.tensorize import Tensorizer
+from simtpu.obs.metrics import REGISTRY
 from simtpu import constants as C
 from simtpu.synth import make_deployment, make_node
 from simtpu.workloads.expand import get_valid_pods_exclude_daemonset
@@ -168,10 +169,13 @@ def test_failed_compile_falls_back_loud(caplog):
     arg = np.zeros(3, np.float32)
     pipe.submit("boom", (), _Boom(), (_sds((3,), np.float32),))
     pipe.wait_all()
+    before = REGISTRY.value("aot.failures")
     with caplog.at_level(logging.WARNING, logger="simtpu.precompile"):
         out = pipe.call("boom", (), (arg,), lambda: "fell-back")
     assert out == "fell-back"
     assert pipe.stats()["failures"] == 1
+    # the process-wide counter chip_smoke.py asserts on
+    assert REGISTRY.value("aot.failures") == before + 1
     assert any("AOT precompile" in rec.message for rec in caplog.records)
     # second call falls back again but does not re-warn (loud once)
     n_warn = len(caplog.records)

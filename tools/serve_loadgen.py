@@ -60,7 +60,6 @@ class Daemon:
 
     def __init__(self, state_dir: str, queue_depth: int, argv_extra=()):
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # the generator lives next to the simtpu package — make the
         # daemon subprocess importable from ANY cwd, installed or not
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -391,6 +390,8 @@ def main(argv=None) -> int:
         base = daemon.base
     summary = {}
     try:
+        _, health, _ = request(base, "GET", "/healthz")
+        summary["serve_platform"] = health["platform"]
         status, doc, _ = request(
             base, "POST", "/v1/sessions", {"config": args.config}
         )
@@ -408,7 +409,7 @@ def main(argv=None) -> int:
         sweep_requests = max(
             int(d.get("serve.requests", 0)) - 2, 1
         )  # minus the deadline/malformed riders
-        summary = {
+        summary.update({
             "serve_qps": round(len(lats) / wall, 2) if wall > 0 else 0.0,
             "serve_p50_s": round(quantile(lats, 0.50), 4),
             "serve_p99_s": round(quantile(lats, 0.99), 4),
@@ -420,7 +421,7 @@ def main(argv=None) -> int:
             "serve_coalesce_ratio": round(
                 int(d.get("serve.coalesced", 0)) / sweep_requests, 4
             ),
-        }
+        })
 
         # burst verdicts: every job answered its expected status
         mis = [
