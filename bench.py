@@ -390,12 +390,8 @@ def big_point() -> dict:
 def device_peak_bytes():
     """Accelerator peak-memory high-water (jax memory_stats), None on
     backends that publish none (CPU) — the on-device half of the byte
-    telemetry next to `state_bytes` and `fetch_bytes`.  Sampled onto the
-    metrics registry (`device.peak_bytes` gauge, ISSUE 8) so the
-    registry snapshot every BENCH point records carries it too."""
+    telemetry next to `state_bytes` and `fetch_bytes`."""
     import jax
-
-    from simtpu.obs.metrics import REGISTRY
 
     try:
         stats = jax.devices()[0].memory_stats()
@@ -403,10 +399,7 @@ def device_peak_bytes():
         return None
     if not stats:
         return None
-    peak = stats.get("peak_bytes_in_use")
-    if peak is not None:
-        REGISTRY.gauge("device.peak_bytes").set(int(peak))
-    return peak
+    return stats.get("peak_bytes_in_use")
 
 
 def layout_point() -> dict:

@@ -290,7 +290,8 @@ class Simulator:
         ]
         if not pods:
             return
-        batch = self._tensorizer.add_pods(pods)
+        with span("schedule.tensorize", pods=len(pods)):
+            batch = self._tensorizer.add_pods(pods)
         if self._precompile:
             from .engine.precompile import precompile_place
 
@@ -303,14 +304,17 @@ class Simulator:
         # the whole batch already); preemption then runs against a consistent
         # view — the analog of failed pods re-entering via the backoff queue
         failed = []
-        for i, (pod, node_idx, reason) in enumerate(zip(batch.pods, nodes, reasons)):
-            if node_idx >= 0:
-                self._record_placed(
-                    pod, node_idx, extras["gpu_shares"][i],
-                    forced=bool(batch.forced[i]),
-                )
-            else:
-                failed.append((pod, int(reason)))
+        with span("schedule.record", pods=len(batch.pods)):
+            for i, (pod, node_idx, reason) in enumerate(
+                zip(batch.pods, nodes, reasons)
+            ):
+                if node_idx >= 0:
+                    self._record_placed(
+                        pod, node_idx, extras["gpu_shares"][i],
+                        forced=bool(batch.forced[i]),
+                    )
+                else:
+                    failed.append((pod, int(reason)))
         self._preempt_failed_batch(failed)
 
     # -- preemption (DefaultPreemption PostFilter analog) -------------------
@@ -996,12 +1000,13 @@ class Simulator:
         return req
 
     def _result(self) -> SimulateResult:
-        by_node = {name_of(n): [] for n in self._nodes}
-        for pod in self._scheduled:
-            by_node[pod["spec"]["nodeName"]].append(shallow_pod_copy(pod))
-        nodes = [deep_copy(n) for n in self._nodes]
-        self._write_extended_annotations(nodes)
-        statuses = [NodeStatus(node=n, pods=by_node[name_of(n)]) for n in nodes]
+        with span("plan.materialize", nodes=len(self._nodes)):
+            by_node = {name_of(n): [] for n in self._nodes}
+            for pod in self._scheduled:
+                by_node[pod["spec"]["nodeName"]].append(shallow_pod_copy(pod))
+            nodes = [deep_copy(n) for n in self._nodes]
+            self._write_extended_annotations(nodes)
+            statuses = [NodeStatus(node=n, pods=by_node[name_of(n)]) for n in nodes]
         return SimulateResult(
             unscheduled_pods=list(self._unscheduled),
             node_status=statuses,

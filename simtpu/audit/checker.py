@@ -791,30 +791,31 @@ def audit_placement(
         else np.asarray(node_valid, bool)
     )
     use_jit = audit_jit_enabled() if jit is None else bool(jit)
-    e = entries if entries is not None else _entries_from_batch(
-        tensors, batch, nodes, ext
-    )
-    report = AuditReport(
-        ok=True, checked=len(e.n), mode="jit" if use_jit else "numpy"
-    )
-
-    if require_all:
-        nodes_a = np.asarray(nodes)
-        exp = (
-            np.ones(len(nodes_a), bool)
-            if expect_mask is None
-            else np.asarray(expect_mask, bool)
+    with span("audit.prepare"):
+        e = entries if entries is not None else _entries_from_batch(
+            tensors, batch, nodes, ext
         )
-        for j in np.flatnonzero((nodes_a < 0) & exp):
-            name = ""
-            if batch is not None and batch.pods:
-                name = (batch.pods[int(j)].get("metadata") or {}).get("name", "")
-            report.add(
-                Violation(
-                    kind=K_UNPLACED, row=int(j), pod=name,
-                    witness={"claimed": "all-or-nothing"},
-                )
+        report = AuditReport(
+            ok=True, checked=len(e.n), mode="jit" if use_jit else "numpy"
+        )
+
+        if require_all:
+            nodes_a = np.asarray(nodes)
+            exp = (
+                np.ones(len(nodes_a), bool)
+                if expect_mask is None
+                else np.asarray(expect_mask, bool)
             )
+            for j in np.flatnonzero((nodes_a < 0) & exp):
+                name = ""
+                if batch is not None and batch.pods:
+                    name = (batch.pods[int(j)].get("metadata") or {}).get("name", "")
+                report.add(
+                    Violation(
+                        kind=K_UNPLACED, row=int(j), pod=name,
+                        witness={"claimed": "all-or-nothing"},
+                    )
+                )
 
     with span("audit.pass", pods=int(len(e.n)), mode=report.mode):
         flags = (

@@ -66,6 +66,11 @@ def enable_compilation_cache() -> str | None:
     is CPU; every disabled exit says so on stderr."""
     import jax
 
+    from .obs.profile import install_jit_listener
+
+    # every caller reaches here before its first compile: the one place
+    # the compile-event listener is registered (obs/profile.py)
+    install_jit_listener()
     env = os.environ.get("SIMTPU_COMPILATION_CACHE", "")
     if env.lower() in _OFF_VALUES:
         _skip_note(f"SIMTPU_COMPILATION_CACHE={env}")

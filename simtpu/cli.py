@@ -16,6 +16,7 @@ import sys
 from typing import List, Optional
 
 from . import __version__, constants as C
+from .obs.trace import span
 from .plan.capacity import Applier, ApplierOptions
 from .report import report
 
@@ -241,7 +242,13 @@ def _sweep_json_doc(sweep, spec: str, samples: int, seed: int) -> dict:
 
 
 def cmd_apply(args: argparse.Namespace) -> int:
-    return _with_obs(args, lambda: _cmd_apply(args))
+    def body() -> int:
+        # the root span of one answer, opened once --trace has armed the
+        # tracer: every span of the answer names it as root
+        with span("apply"):
+            return _cmd_apply(args)
+
+    return _with_obs(args, body)
 
 
 def _cmd_apply(args: argparse.Namespace) -> int:
@@ -345,19 +352,20 @@ def _cmd_apply(args: argparse.Namespace) -> int:
             fault_error = str(exc)
             print(f"fault sweep failed: {exc}", file=sys.stderr)
     if args.json:
-        resilience = None
-        if fault_sweep is not None:
-            resilience = _sweep_json_doc(
-                fault_sweep, args.faults, args.fault_samples, args.fault_seed
-            )
-            resilience["base_unplaced"] = fault_base_unplaced
-            if fault_audit is not None:
-                resilience["audit"] = fault_audit
-        elif fault_error is not None:
-            resilience = {"error": fault_error}
-            if fault_audit is not None:
-                resilience["audit"] = fault_audit
-        print(_plan_json(plan, resilience=resilience))
+        with span("report"):
+            resilience = None
+            if fault_sweep is not None:
+                resilience = _sweep_json_doc(
+                    fault_sweep, args.faults, args.fault_samples, args.fault_seed
+                )
+                resilience["base_unplaced"] = fault_base_unplaced
+                if fault_audit is not None:
+                    resilience["audit"] = fault_audit
+            elif fault_error is not None:
+                resilience = {"error": fault_error}
+                if fault_audit is not None:
+                    resilience["audit"] = fault_audit
+            print(_plan_json(plan, resilience=resilience))
         if plan.partial:
             return _flight_exit(
                 EXIT_PARTIAL, "partial result (deadline/SIGINT)", args, plan
@@ -371,7 +379,8 @@ def _cmd_apply(args: argparse.Namespace) -> int:
     if plan.success:
         print(f"{C.COLOR_GREEN}Success!{C.COLOR_RESET}")
         print(C.COLOR_GREEN, end="")
-        print(report(plan.result.node_status, opts.extended_resources))
+        with span("report"):
+            print(report(plan.result.node_status, opts.extended_resources))
         print(C.COLOR_RESET, end="")
         if plan.audit:
             from .report import audit_report
@@ -436,7 +445,8 @@ def _cmd_apply(args: argparse.Namespace) -> int:
         print(explain_report(plan.explain))
     if plan.result is not None:
         print(C.COLOR_RED, end="")
-        print(report(plan.result.node_status, opts.extended_resources))
+        with span("report"):
+            print(report(plan.result.node_status, opts.extended_resources))
         print(C.COLOR_RESET, end="")
     if plan.partial:
         return _flight_exit(

@@ -58,7 +58,7 @@ from typing import Optional
 import numpy as np
 
 from ..obs.metrics import REGISTRY
-from ..obs.trace import span
+from ..obs.trace import adopt, current, span
 
 log = logging.getLogger("simtpu.precompile")
 
@@ -175,17 +175,20 @@ class AotPipeline:
             self._jobs[key] = job
             if self._t0 is None:
                 self._t0 = time.perf_counter()
+            # the span that caused this compile, taken at submit: the pool
+            # thread's compile span names it as parent and root
             job.future = self._pool.submit(
-                self._compile, job, name, fn, args_sds, static_tail
+                self._compile, job, name, fn, args_sds, static_tail,
+                current(),
             )
         return True
 
-    def _compile(self, job, name, fn, args_sds, static_tail):
+    def _compile(self, job, name, fn, args_sds, static_tail, cause):
         t0 = time.perf_counter()
         # per-signature compile span ON the pool thread: the Perfetto view
         # shows the compile lanes overlapping the dispatch lane — the
         # pipelining win (and any straggler signature) made visible
-        with span("aot.compile", sig=str(name)):
+        with adopt(cause), span("aot.compile", sig=str(name)):
             compiled = fn.lower(*args_sds, *static_tail).compile()
         job.seconds = time.perf_counter() - t0
         with self._lock:
@@ -418,20 +421,21 @@ def precompile_place(
 
     pipe = pipeline if pipeline is not None else AotPipeline(workers)
     engine.pipeline = pipe
-    tensors = engine.tensorizer.freeze()
-    statics = statics_from(tensors, engine.sched_config)
-    flags = flags_from(tensors, batch.ext)
-    _, pods = build_pod_arrays(batch, tensors.alloc.shape[1])
-    st_sds, state_tree = engine._precompile_shapes(
-        _as_sds(statics), state_sds(tensors)
-    )
-    if isinstance(engine, RoundsEngine):
-        _plan_bulk_jobs(
-            pipe, engine, tensors, batch, st_sds, state_tree, pods, flags
+    with span("aot.enumerate", pods=len(batch.group)):
+        tensors = engine.tensorizer.freeze()
+        statics = statics_from(tensors, engine.sched_config)
+        flags = flags_from(tensors, batch.ext)
+        _, pods = build_pod_arrays(batch, tensors.alloc.shape[1])
+        st_sds, state_tree = engine._precompile_shapes(
+            _as_sds(statics), state_sds(tensors)
         )
-    else:
-        _plan_scan_jobs(
-            pipe, engine, tensors, st_sds, state_tree, pods,
-            np.asarray(batch.group), flags,
-        )
+        if isinstance(engine, RoundsEngine):
+            _plan_bulk_jobs(
+                pipe, engine, tensors, batch, st_sds, state_tree, pods, flags
+            )
+        else:
+            _plan_scan_jobs(
+                pipe, engine, tensors, st_sds, state_tree, pods,
+                np.asarray(batch.group), flags,
+            )
     return pipe

@@ -3207,95 +3207,100 @@ class Engine:
         extras carries each pod's extended-resource allocation at its node
         (LVM per-VG bytes, device take mask, GPU device shares).
         """
-        tensors = self.tensorizer.freeze()
-        # batch context for _dispatch overrides (RoundsEngine segments pods
-        # by group/spec and needs the frozen tensors without re-freezing)
-        self._current_batch = batch
-        self._current_tensors = tensors
-        r = tensors.alloc.shape[1]
-        req, pods = build_pod_arrays(batch, r)
-        # carry the previous batch's final state forward when nothing that
-        # shapes it changed; a grown vocabulary (new groups may retro-match
-        # new terms) or log surgery (preemption) forces the full rebuild.
-        # The interpod-plane count participates: a new group can mark an
-        # ALREADY-interned term as interpod-used without growing n_terms,
-        # which reshapes the compacted own planes.
-        vocab = self.state_vocab(tensors)
-        if (
-            self.last_state is not None
-            and not self._state_dirty
-            and self._last_vocab == vocab
-        ):
-            state = self.last_state
-            if isinstance(state, CompactState):
-                # one-gather expansion back to the dense in-kernel form;
-                # the compact carry itself is NOT donated, so a failed
-                # dispatch below leaves it intact for the log fallback
-                state = self._expand_carry(tensors, state)
-        else:
-            state = None
+        with span("engine.prepare"):
+            tensors = self.tensorizer.freeze()
+            # batch context for _dispatch overrides (RoundsEngine segments pods
+            # by group/spec and needs the frozen tensors without re-freezing)
+            self._current_batch = batch
+            self._current_tensors = tensors
+            r = tensors.alloc.shape[1]
+            req, pods = build_pod_arrays(batch, r)
+            # carry the previous batch's final state forward when nothing that
+            # shapes it changed; a grown vocabulary (new groups may retro-match
+            # new terms) or log surgery (preemption) forces the full rebuild.
+            # The interpod-plane count participates: a new group can mark an
+            # ALREADY-interned term as interpod-used without growing n_terms,
+            # which reshapes the compacted own planes.
+            vocab = self.state_vocab(tensors)
             if (
-                self.grow
-                and self.last_state is not None
+                self.last_state is not None
                 and not self._state_dirty
-                and not isinstance(self.last_state, CompactState)
+                and self._last_vocab == vocab
             ):
-                # append-only vocabulary growth: extend the carried planes
-                # in place instead of rebuilding from the log
-                state = self._try_extend_carry(tensors, vocab)
-            if state is None:
-                if self.grow and self._grow_ref is not None:
-                    REGISTRY.counter("grow.rebuilds").inc()
-                state = build_state(
-                    tensors,
-                    np.asarray(self.placed_group, np.int32),
-                    np.asarray(self.placed_node, np.int32),
-                    self.log_req_matrix(r),
-                    self.ext_log,
+                state = self.last_state
+                if isinstance(state, CompactState):
+                    # one-gather expansion back to the dense in-kernel form;
+                    # the compact carry itself is NOT donated, so a failed
+                    # dispatch below leaves it intact for the log fallback
+                    state = self._expand_carry(tensors, state)
+            else:
+                state = None
+                if (
+                    self.grow
+                    and self.last_state is not None
+                    and not self._state_dirty
+                    and not isinstance(self.last_state, CompactState)
+                ):
+                    # append-only vocabulary growth: extend the carried planes
+                    # in place instead of rebuilding from the log
+                    state = self._try_extend_carry(tensors, vocab)
+                if state is None:
+                    if self.grow and self._grow_ref is not None:
+                        REGISTRY.counter("grow.rebuilds").inc()
+                    state = build_state(
+                        tensors,
+                        np.asarray(self.placed_group, np.int32),
+                        np.asarray(self.placed_node, np.int32),
+                        self.log_req_matrix(r),
+                        self.ext_log,
+                    )
+                    if self.grow:
+                        state = self._enter_grow_buckets(tensors, state)
+            statics = statics_from(tensors, self.sched_config)
+            if self.node_valid is not None:
+                # fault/what-if masking: dead rows no pod can select — the same
+                # lever the capacity sweep vmaps over (parallel/sweep.py)
+                statics = statics._replace(
+                    node_valid=statics.node_valid
+                    & jnp.asarray(np.asarray(self.node_valid, bool))
                 )
-                if self.grow:
-                    state = self._enter_grow_buckets(tensors, state)
-        statics = statics_from(tensors, self.sched_config)
-        if self.node_valid is not None:
-            # fault/what-if masking: dead rows no pod can select — the same
-            # lever the capacity sweep vmaps over (parallel/sweep.py)
-            statics = statics._replace(
-                node_valid=statics.node_valid
-                & jnp.asarray(np.asarray(self.node_valid, bool))
-            )
-        ext = batch.ext
-        flags = flags_from(tensors, batch.ext)
+            ext = batch.ext
+            flags = flags_from(tensors, batch.ext)
         # a donating dispatch can invalidate `state`'s buffers before raising
         # (RoundsEngine makes several donating calls per batch); mark dirty so
         # a retry rebuilds from the log instead of reusing a dead buffer
         self._state_dirty = True
-        final_state, (nodes, reasons, lvm_alloc, dev_take, gpu_shares) = self._dispatch(
-            statics, state, pods, flags
-        )
-        # the dense final state simply goes unreferenced after this call —
-        # compression deliberately does NOT donate it (int32 outputs cannot
-        # alias f32 inputs; see the audit note on compress_state); what is
-        # stored — and what every later expansion reproduces bit-identically
-        # — is the domain-tabular carry
-        self.last_state = self._store_state(tensors, final_state)
-        # cache bookkeeping only after a successful dispatch: a failed run
-        # must not leave the reuse branch validating a stale/donated state
-        self._last_vocab = vocab
-        self._state_dirty = False
-        nodes = np.asarray(nodes)
-        reasons = np.asarray(reasons)
-        lvm_alloc = np.asarray(lvm_alloc)
-        dev_take = np.asarray(dev_take)
-        gpu_shares = np.asarray(gpu_shares)
-        ok = np.flatnonzero(nodes >= 0)
-        self.placed_group.extend(np.asarray(batch.group)[ok].tolist())
-        self.placed_node.extend(nodes[ok].tolist())
-        self.placed_req.extend(req[ok])
-        self.ext_log["node"].extend(nodes[ok].tolist())
-        self.ext_log["vg_alloc"].extend(lvm_alloc[ok])
-        self.ext_log["sdev_take"].extend(dev_take[ok])
-        self.ext_log["gpu_shares"].extend(gpu_shares[ok])
-        self.ext_log["gpu_mem"].extend(np.asarray(ext["gpu_mem"])[ok].tolist())
+        # the host loop between dispatches (chunk plans, wavefront
+        # drafts, recording) is this span's self time
+        with span("engine.dispatch", pods=len(batch.group)):
+            final_state, (nodes, reasons, lvm_alloc, dev_take, gpu_shares) = (
+                self._dispatch(statics, state, pods, flags)
+            )
+        with span("engine.record"):
+            # the dense final state simply goes unreferenced after this call —
+            # compression deliberately does NOT donate it (int32 outputs cannot
+            # alias f32 inputs; see the audit note on compress_state); what is
+            # stored — and what every later expansion reproduces bit-identically
+            # — is the domain-tabular carry
+            self.last_state = self._store_state(tensors, final_state)
+            # cache bookkeeping only after a successful dispatch: a failed run
+            # must not leave the reuse branch validating a stale/donated state
+            self._last_vocab = vocab
+            self._state_dirty = False
+            nodes = np.asarray(nodes)
+            reasons = np.asarray(reasons)
+            lvm_alloc = np.asarray(lvm_alloc)
+            dev_take = np.asarray(dev_take)
+            gpu_shares = np.asarray(gpu_shares)
+            ok = np.flatnonzero(nodes >= 0)
+            self.placed_group.extend(np.asarray(batch.group)[ok].tolist())
+            self.placed_node.extend(nodes[ok].tolist())
+            self.placed_req.extend(req[ok])
+            self.ext_log["node"].extend(nodes[ok].tolist())
+            self.ext_log["vg_alloc"].extend(lvm_alloc[ok])
+            self.ext_log["sdev_take"].extend(dev_take[ok])
+            self.ext_log["gpu_shares"].extend(gpu_shares[ok])
+            self.ext_log["gpu_mem"].extend(np.asarray(ext["gpu_mem"])[ok].tolist())
         return nodes, reasons, {
             "lvm_alloc": lvm_alloc,
             "dev_take": dev_take,

@@ -15,6 +15,8 @@ from typing import List
 import yaml
 
 from ..core.objects import ResourceTypes
+from ..obs.metrics import REGISTRY
+from ..obs.trace import span
 
 
 def parse_file_paths(path: str) -> List[str]:
@@ -82,16 +84,26 @@ def get_objects_from_yaml_content(docs: List[str]) -> ResourceTypes:
     """Type-switch decoded docs into ResourceTypes; unknown kinds are
     skipped (reference parity — app bundles legitimately carry Services,
     ConfigMaps...).  Objects from `SourcedText` docs are stamped with
-    their manifest file for spec diagnostics."""
+    their manifest file for spec diagnostics.
+
+    Every text is decoded first, then the objects are built, so the two
+    stages are two spans (`ingest.decode`, `ingest.objects`); the
+    `ingest.docs` / `ingest.bytes` counters give decode its rate."""
     from ..workloads.expand import SOURCE_KEY
 
+    with span("ingest.decode", texts=len(docs)):
+        decoded = [decode_yaml_content(text) for text in docs]
+    n_docs = sum(len(objs) for objs in decoded)
+    REGISTRY.counter("ingest.docs").inc(n_docs)
+    REGISTRY.counter("ingest.bytes").inc(sum(len(t.encode()) for t in docs))
     resources = ResourceTypes()
-    for text in docs:
-        source = getattr(text, "source", None)
-        for obj in decode_yaml_content(text):
-            if source:
-                obj[SOURCE_KEY] = source
-            resources.add(obj)
+    with span("ingest.objects", docs=n_docs):
+        for text, objs in zip(docs, decoded):
+            source = getattr(text, "source", None)
+            for obj in objs:
+                if source:
+                    obj[SOURCE_KEY] = source
+                resources.add(obj)
     return resources
 
 

@@ -28,7 +28,7 @@ Instruments:
 Naming: `<family>.<field>`, lowercase, dots as the only separator —
 `fetch.get`, `fetch.bytes`, `compile.scan`, `wavefront.rollback_pods`,
 `backoff.events`, `state.carried_bytes`, `audit.total_violations`,
-`device.peak_bytes`.  The full table lives in docs/observability.md.
+`jit.compile_s`.  The full table lives in docs/observability.md.
 """
 
 from __future__ import annotations

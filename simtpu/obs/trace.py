@@ -23,25 +23,45 @@ Design constraints (measured by `make bench-obs`):
 - **Thread-safe.** The AOT precompile pool opens compile spans from
   worker threads concurrently with the dispatch loop's chunk spans; the
   ring index is bumped under one lock at span EXIT only (one lock
-  acquisition per completed span, nothing on entry).
+  acquisition per completed span; on entry only a root span takes it,
+  for its clock anchor).
+
+Identity and causality: every span gets an integer id (`sid`), the id of
+the span that encloses it on its thread (`parent`, None for a root) and
+the id of its outermost span (`root`: one request or answer).  Work
+handed to another thread keeps its cause: capture `current()` where the
+work is submitted and run it under `adopt(ctx)`, so the worker's spans
+take the submitting span as parent and root (engine/precompile.py does
+this for the AOT pool).  `complete()` records a span after the fact from
+a measured duration (the `jit.*` compile events of obs/profile.py).
+
+Clock anchors: the ring runs on `time.perf_counter_ns()`.  `enable()` and
+the start of every root span record an `obs.clock` instant holding a
+(ring ns, `time.time_ns()`) pair taken back to back; `profiler_ns()` maps
+a ring timestamp through the nearest anchors onto the wall clock, the
+host clock of `jax.profiler` traces, which store it less their
+`profile_start_time`.
 
 Export is the Chrome trace-event JSON object format — `{"traceEvents":
 [...]}` with complete ("ph": "X") events — loadable directly in Perfetto
 (https://ui.perfetto.dev) or chrome://tracing.  Timestamps are
 microseconds from an arbitrary per-process origin, durations are
 microseconds, `tid` is the Python thread ident (named via metadata
-events).  `simtpu apply --trace FILE` writes one; SIMTPU_TRACE=1 arms
+events); `args` carries `depth`, `parent` and `root`, and `otherData`
+the clock anchors.  `simtpu apply --trace FILE` writes one; SIMTPU_TRACE=1 arms
 in-memory tracing (SIMTPU_TRACE=<path> also exports at process exit —
 the hook tools/run_tests.py uses for its slowest-spans summary).
 """
 
 from __future__ import annotations
 
+import bisect
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 DEFAULT_CAPACITY = 65536
 
@@ -51,7 +71,13 @@ _RING: List[Optional[tuple]] = []
 _COUNT = 0  # total events ever recorded (ring index = _COUNT % capacity)
 _DROPPED = 0  # events overwritten after wraparound
 _T0 = time.perf_counter_ns()  # per-process trace origin
-_TLS = threading.local()  # per-thread span depth (nesting attribute)
+#: per-thread state: `stack`, the (sid, root) of each open span, and
+#: `inherit`, the (parent, root) a worker thread adopted from its submitter
+_TLS = threading.local()
+_IDS = itertools.count(1)  # span ids; next() is atomic under the GIL
+#: (ring ns since _T0, time.time_ns()) pairs, oldest first
+_ANCHORS: List[Tuple[int, int]] = []
+ANCHOR_CAP = 4096  # a long traced serve run keeps its newest anchors
 
 #: set by obs/profile.py while a jax.profiler capture is live: a callable
 #: name -> context manager (jax.profiler.TraceAnnotation) entered by every
@@ -80,9 +106,9 @@ _NOOP = _NoopSpan()
 
 class _Span:
     """One live span: records (name, start, duration, thread, depth,
-    attrs) into the ring on exit."""
+    attrs, sid, parent, root) into the ring on exit."""
 
-    __slots__ = ("name", "attrs", "_t0", "_depth", "_ann")
+    __slots__ = ("name", "attrs", "_t0", "_depth", "_ann", "_sid", "_parent", "_root")
 
     def __init__(self, name: str, attrs: Optional[dict]):
         self.name = name
@@ -98,9 +124,12 @@ class _Span:
         return self
 
     def __enter__(self):
-        depth = getattr(_TLS, "depth", 0)
-        _TLS.depth = depth + 1
-        self._depth = depth
+        sid = self._sid = next(_IDS)
+        self._depth, self._parent, root = _context()
+        self._root = sid if root is None else root
+        _stack().append((sid, self._root))
+        if root is None:
+            anchor()  # one clock anchor per root: per request or answer
         ann = None
         factory = _ANNOTATION_FACTORY
         if factory is not None:
@@ -120,24 +149,48 @@ class _Span:
                 self._ann.__exit__(*exc)
             except Exception:  # noqa: BLE001
                 pass
-        _TLS.depth = self._depth
-        global _COUNT, _DROPPED
-        event = (
+        # truncate rather than pop: a span leaked inside this one must not
+        # corrupt the thread's nesting for every later span
+        del _stack()[self._depth:]
+        _record((
             self.name,
             (self._t0 - _T0) // 1000,  # ts, us
             max((t1 - self._t0) // 1000, 1),  # dur, us (Perfetto drops 0)
             threading.get_ident(),
             self._depth,
             self.attrs,
-        )
-        with _LOCK:
-            if _ENABLED:  # disabled mid-span: drop, buffers already cleared
-                cap = len(_RING)
-                if _COUNT >= cap:
-                    _DROPPED += 1
-                _RING[_COUNT % cap] = event
-                _COUNT += 1
+            self._sid,
+            self._parent,
+            self._root,
+        ))
         return False
+
+
+def _stack() -> list:
+    stack = getattr(_TLS, "stack", None)
+    if stack is None:
+        stack = _TLS.stack = []
+    return stack
+
+
+def _context() -> tuple:
+    """(depth, parent, root) for an event recorded now on this thread."""
+    stack = _stack()
+    if stack:
+        return (len(stack),) + stack[-1]
+    inherited = getattr(_TLS, "inherit", None)
+    return (0,) + (inherited or (None, None))
+
+
+def _record(event: tuple) -> None:
+    global _COUNT, _DROPPED
+    with _LOCK:
+        if _ENABLED:  # disabled mid-span: drop, buffers already cleared
+            cap = len(_RING)
+            if _COUNT >= cap:
+                _DROPPED += 1
+            _RING[_COUNT % cap] = event
+            _COUNT += 1
 
 
 def span(name: str, **attrs):
@@ -150,26 +203,101 @@ def span(name: str, **attrs):
     return _Span(name, attrs or None)
 
 
+def _point(name: str, ts_us: int, dur_us: int, attrs: Optional[dict]) -> None:
+    """Record an event that never sat on the span stack, under the span
+    open on this thread (its own root when none is)."""
+    depth, parent, root = _context()
+    sid = next(_IDS)
+    _record((
+        name, ts_us, dur_us, threading.get_ident(), depth, attrs, sid,
+        parent, sid if root is None else root,
+    ))
+
+
 def instant(name: str, **attrs) -> None:
     """Record a zero-duration point event (e.g. a wavefront rollback)."""
     if not _ENABLED:
         return
-    global _COUNT, _DROPPED
-    event = (
-        name,
-        (time.perf_counter_ns() - _T0) // 1000,
-        0,
-        threading.get_ident(),
-        getattr(_TLS, "depth", 0),
-        attrs or None,
-    )
+    _point(name, (time.perf_counter_ns() - _T0) // 1000, 0, attrs or None)
+
+
+def complete(name: str, seconds: float, **attrs) -> None:
+    """Record a span that ends now and lasted `seconds`, measured by
+    someone else (a JAX compile event): it nests under the span open on
+    this thread, like a span opened and closed around the same work."""
+    if not _ENABLED:
+        return
+    t1 = time.perf_counter_ns()
+    dur = int(seconds * 1e9)
+    _point(name, (t1 - dur - _T0) // 1000, max(dur // 1000, 1), attrs or None)
+
+
+def anchor() -> None:
+    """Record an `obs.clock` instant: the ring clock and `time.time_ns()`
+    read back to back, so ring timestamps map onto the wall clock (and
+    from there onto a `jax.profiler` trace) through `profiler_ns()`."""
+    if not _ENABLED:
+        return
+    ts_ns = time.perf_counter_ns() - _T0
+    wall_ns = time.time_ns()
     with _LOCK:
-        if _ENABLED:
-            cap = len(_RING)
-            if _COUNT >= cap:
-                _DROPPED += 1
-            _RING[_COUNT % cap] = event
-            _COUNT += 1
+        if len(_ANCHORS) >= ANCHOR_CAP:
+            del _ANCHORS[: ANCHOR_CAP // 2]
+        _ANCHORS.append((ts_ns, wall_ns))
+    _point("obs.clock", ts_ns // 1000, 0, {"ts_ns": ts_ns, "wall_ns": wall_ns})
+
+
+def profiler_ns(ts_us: float) -> Optional[int]:
+    """Map a ring timestamp (us, the `ts` of an event) onto the wall
+    clock in ns: linearly between the two anchors around it, at the rate
+    of the nearest one outside them.  Monotone in `ts_us` while the wall
+    clock does not step back.  None before any anchor."""
+    with _LOCK:
+        anchors = _ANCHORS
+        if not anchors:
+            return None
+        t = round(ts_us * 1000)  # integers: wall ns overflow a float's mantissa
+        i = bisect.bisect_right(anchors, (t, 1 << 63))
+        if i == 0 or i == len(anchors):
+            a, w = anchors[0] if i == 0 else anchors[-1]
+            return w + t - a
+        (a0, w0), (a1, w1) = anchors[i - 1], anchors[i]
+    return w0 + (t - a0) * (w1 - w0) // (a1 - a0)
+
+
+def current() -> Optional[tuple]:
+    """The (span id, root id) that work submitted from here should run
+    under — pass it to `adopt()` on the worker thread.  None when tracing
+    is off or no span is open."""
+    if not _ENABLED:
+        return None
+    stack = _stack()
+    return stack[-1] if stack else getattr(_TLS, "inherit", None)
+
+
+class _Adopt:
+    __slots__ = ("ctx", "prev")
+
+    def __init__(self, ctx: tuple):
+        self.ctx = ctx
+
+    def __enter__(self):
+        self.prev = getattr(_TLS, "inherit", None)
+        _TLS.inherit = self.ctx
+        return self
+
+    def __exit__(self, *exc):
+        _TLS.inherit = self.prev
+        return False
+
+
+def adopt(ctx: Optional[tuple]):
+    """Run a worker's spans under `ctx`, a `current()` captured where the
+    work was submitted: they take its span as parent and its root as
+    root.  `adopt(None)` is the shared no-op."""
+    if ctx is None:
+        return _NOOP
+    return _Adopt(ctx)
 
 
 def enabled() -> bool:
@@ -186,7 +314,9 @@ def enable(capacity: int = DEFAULT_CAPACITY) -> None:
         _RING = [None] * capacity
         _COUNT = 0
         _DROPPED = 0
+        _ANCHORS.clear()
         _ENABLED = True
+    anchor()
 
 
 def disable() -> None:
@@ -197,12 +327,13 @@ def disable() -> None:
         _RING = []
         _COUNT = 0
         _DROPPED = 0
+        _ANCHORS.clear()
 
 
 def events() -> List[tuple]:
     """Chronological snapshot of the buffered events — oldest surviving
     first (wraparound drops the oldest).  Tuples of (name, ts_us, dur_us,
-    tid, depth, attrs)."""
+    tid, depth, attrs, sid, parent, root)."""
     with _LOCK:
         if not _RING:
             return []
@@ -225,6 +356,8 @@ def to_chrome_trace(last: Optional[int] = None) -> Dict[str, object]:
     evs = events()
     if last is not None:
         evs = evs[-last:]
+    with _LOCK:
+        anchors = [list(a) for a in _ANCHORS]
     pid = os.getpid()
     trace_events: List[dict] = [
         {
@@ -236,8 +369,8 @@ def to_chrome_trace(last: Optional[int] = None) -> Dict[str, object]:
         }
     ]
     tids = []
-    for name, ts, dur, tid, depth, attrs in evs:
-        args = {"depth": depth}
+    for name, ts, dur, tid, depth, attrs, _sid, parent, root in evs:
+        args = {"depth": depth, "parent": parent, "root": root}
         if attrs:
             args.update(attrs)
         if dur == 0:
@@ -281,7 +414,12 @@ def to_chrome_trace(last: Optional[int] = None) -> Dict[str, object]:
     return {
         "traceEvents": trace_events,
         "displayTimeUnit": "ms",
-        "otherData": {"dropped_events": _DROPPED},
+        "otherData": {
+            "dropped_events": _DROPPED,
+            # [ring ns, time.time_ns()] pairs: `ts` * 1000 - ring ns + wall
+            # ns is the wall clock a jax.profiler trace runs on
+            "clock_anchors": anchors,
+        },
     }
 
 
@@ -300,7 +438,7 @@ def span_summary(top: int = 10) -> List[dict]:
     """Top-N span names by total wall-clock: [{"name", "count",
     "total_s", "max_s"}] — the run_tests / flight-recorder digest."""
     agg: Dict[str, List[float]] = {}
-    for name, _, dur, _, _, _ in events():
+    for name, _, dur, *_ in events():
         row = agg.setdefault(name, [0, 0.0, 0.0])
         row[0] += 1
         row[1] += dur / 1e6

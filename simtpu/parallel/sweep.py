@@ -37,6 +37,7 @@ from ..engine.scan import (
     statics_from,
 )
 from ..engine.state import build_state
+from ..obs.trace import span
 from ..workloads.expand import (
     get_valid_pods_exclude_daemonset,
     make_valid_pods_by_daemonset,
@@ -91,31 +92,34 @@ def assemble_planning_problem(
     n_base = len(base_nodes)
     all_nodes = base_nodes + new_fake_nodes(new_node, max_new)
 
-    ordered: List[dict] = []
-    work = ResourceTypes(**{k: list(v) for k, v in vars(cluster).items()})
-    work.nodes = all_nodes
-    cluster_pods = get_valid_pods_exclude_daemonset(work)
-    for ds in work.daemon_sets:
-        cluster_pods.extend(make_valid_pods_by_daemonset(ds, all_nodes))
-    ordered.extend(cluster_pods)
     from ..api import _sort_app_pods
 
-    for app in apps:
-        pods = get_valid_pods_exclude_daemonset(app.resource)
-        for ds in app.resource.daemon_sets:
-            pods.extend(make_valid_pods_by_daemonset(ds, all_nodes))
-        for pod in pods:
-            set_label(pod, C.LABEL_APP_NAME, app.name)
-        ordered.extend(_sort_app_pods(pods))
+    ordered: List[dict] = []
+    with span("expand") as sp:
+        work = ResourceTypes(**{k: list(v) for k, v in vars(cluster).items()})
+        work.nodes = all_nodes
+        cluster_pods = get_valid_pods_exclude_daemonset(work)
+        for ds in work.daemon_sets:
+            cluster_pods.extend(make_valid_pods_by_daemonset(ds, all_nodes))
+        ordered.extend(cluster_pods)
+        for app in apps:
+            pods = get_valid_pods_exclude_daemonset(app.resource)
+            for ds in app.resource.daemon_sets:
+                pods.extend(make_valid_pods_by_daemonset(ds, all_nodes))
+            for pod in pods:
+                set_label(pod, C.LABEL_APP_NAME, app.name)
+            ordered.extend(_sort_app_pods(pods))
+        sp.set(pods=len(ordered))
 
-    tensorizer = Tensorizer(
-        all_nodes,
-        extended_resources,
-        storage_classes=list(cluster.storage_classes),
-        services=list(cluster.services),
-        pvcs=list(cluster.persistent_volume_claims),
-        pvs=list(cluster.persistent_volumes),
-    )
+    with span("tensorize", nodes=len(all_nodes)):
+        tensorizer = Tensorizer(
+            all_nodes,
+            extended_resources,
+            storage_classes=list(cluster.storage_classes),
+            services=list(cluster.services),
+            pvcs=list(cluster.persistent_volume_claims),
+            pvs=list(cluster.persistent_volumes),
+        )
     return tensorizer, all_nodes, n_base, ordered
 
 

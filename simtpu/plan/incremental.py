@@ -394,8 +394,9 @@ def _plan_capacity_incremental(
         tz, all_nodes, n_base, ordered = assemble_planning_problem(
             cluster, apps, new_node, max_new, extended_resources
         )
-        batch = tz.add_pods(ordered)
-        tensors = tz.freeze()
+        with span("tensorize", pods=len(ordered)):
+            batch = tz.add_pods(ordered)
+            tensors = tz.freeze()
         statics_from(tensors, sched_config)  # transfer device statics once
         vocab = _vocab_of(tensors)
         pin = np.asarray(batch.pin)
@@ -767,22 +768,23 @@ def _plan_capacity_incremental(
         all_ds = list(cluster.daemon_sets)
         for app in apps:
             all_ds += app.resource.daemon_sets
-        for j in failed_idx[:64]:  # a handful suffices for the message
-            pod = ordered[int(j)]
-            if not node_should_run_pod(new_node, pod):
-                return (
-                    f"failed to schedule pod {namespace_of(pod)}/{name_of(pod)}: "
-                    "the pod cannot be scheduled successfully by adding node: "
-                    "pod does not fit new node affinity or taints"
-                )
-            if not meet_resource_requests(
-                new_node, pod, all_ds, corrected=corrected_ds_overhead
-            ):
-                return (
-                    f"failed to schedule pod {namespace_of(pod)}/{name_of(pod)}: "
-                    "new node cannot meet resource requests of pod: the total "
-                    "requested resource of daemonset pods in new node is too large"
-                )
+        with span("plan.diagnose"):
+            for j in failed_idx[:64]:  # a handful suffices for the message
+                pod = ordered[int(j)]
+                if not node_should_run_pod(new_node, pod):
+                    return (
+                        f"failed to schedule pod {namespace_of(pod)}/{name_of(pod)}: "
+                        "the pod cannot be scheduled successfully by adding node: "
+                        "pod does not fit new node affinity or taints"
+                    )
+                if not meet_resource_requests(
+                    new_node, pod, all_ds, corrected=corrected_ds_overhead
+                ):
+                    return (
+                        f"failed to schedule pod {namespace_of(pod)}/{name_of(pod)}: "
+                        "new node cannot meet resource requests of pod: the total "
+                        "requested resource of daemonset pods in new node is too large"
+                    )
         return None
 
     msg = diagnose(u0)
@@ -1012,33 +1014,34 @@ def _materialize(
     engine-level placement vector (one pass, no per-probe Python cost)."""
     from ..api import record_placed_pod, write_extended_annotations
 
-    node_objs = [deep_copy(n) for n in all_nodes[:n_nodes]]
-    write_extended_annotations(tz.ext, ext_log, node_objs)
-    names = [name_of(n) for n in node_objs]
-    by_node: List[List[dict]] = [[] for _ in range(n_nodes)]
-    unscheduled: List[UnscheduledPod] = []
-    gpu_shares_arr = np.asarray(gpu_shares_arr)
-    phantom = clone_of >= n_clones
-    for j in np.flatnonzero((nodes_arr >= 0) & ~phantom):
-        pod = batch.pods[int(j)]
-        by_node[int(nodes_arr[j])].append(
-            record_placed_pod(pod, names[int(nodes_arr[j])], gpu_shares_arr[j])
-        )
-    for j in np.flatnonzero((nodes_arr < 0) & ~phantom):
-        pod = batch.pods[int(j)]
-        msg = REASON_TEXT.get(int(reasons[j]), "unschedulable")
-        unscheduled.append(
-            UnscheduledPod(
-                pod=pod,
-                reason=(
-                    f"failed to schedule pod ({namespace_of(pod)}/{name_of(pod)}): "
-                    f"Unschedulable: 0/{n_nodes} nodes are available: {msg}"
-                ),
+    with span("plan.materialize", nodes=int(n_nodes)):
+        node_objs = [deep_copy(n) for n in all_nodes[:n_nodes]]
+        write_extended_annotations(tz.ext, ext_log, node_objs)
+        names = [name_of(n) for n in node_objs]
+        by_node: List[List[dict]] = [[] for _ in range(n_nodes)]
+        unscheduled: List[UnscheduledPod] = []
+        gpu_shares_arr = np.asarray(gpu_shares_arr)
+        phantom = clone_of >= n_clones
+        for j in np.flatnonzero((nodes_arr >= 0) & ~phantom):
+            pod = batch.pods[int(j)]
+            by_node[int(nodes_arr[j])].append(
+                record_placed_pod(pod, names[int(nodes_arr[j])], gpu_shares_arr[j])
             )
-        )
-    statuses = [
-        NodeStatus(node=n, pods=by_node[i]) for i, n in enumerate(node_objs)
-    ]
+        for j in np.flatnonzero((nodes_arr < 0) & ~phantom):
+            pod = batch.pods[int(j)]
+            msg = REASON_TEXT.get(int(reasons[j]), "unschedulable")
+            unscheduled.append(
+                UnscheduledPod(
+                    pod=pod,
+                    reason=(
+                        f"failed to schedule pod ({namespace_of(pod)}/{name_of(pod)}): "
+                        f"Unschedulable: 0/{n_nodes} nodes are available: {msg}"
+                    ),
+                )
+            )
+        statuses = [
+            NodeStatus(node=n, pods=by_node[i]) for i, n in enumerate(node_objs)
+        ]
     return SimulateResult(
         unscheduled_pods=unscheduled, node_status=statuses, preempted_pods=[]
     )

@@ -41,15 +41,20 @@ def tracer():
         obs_trace.disable()
 
 
+def _spans():
+    """The buffered events less the tracer's own `obs.clock` anchors."""
+    return [e for e in obs_trace.events() if e[0] != "obs.clock"]
+
+
 class TestSpanTracer:
     def test_nesting_depth_and_containment(self, tracer):
         with obs_trace.span("outer", phase="x"):
             with obs_trace.span("inner"):
                 pass
-        evs = {e[0]: e for e in obs_trace.events()}
+        evs = {e[0]: e for e in _spans()}
         assert set(evs) == {"outer", "inner"}
-        name, ts_o, dur_o, _, depth_o, attrs = evs["outer"]
-        _, ts_i, dur_i, _, depth_i, _ = evs["inner"]
+        name, ts_o, dur_o, _, depth_o, attrs, *_ = evs["outer"]
+        _, ts_i, dur_i, _, depth_i, *_ = evs["inner"]
         assert depth_o == 0 and depth_i == 1
         assert attrs == {"phase": "x"}
         # the inner interval is contained in the outer one
@@ -58,7 +63,7 @@ class TestSpanTracer:
     def test_mid_span_attributes(self, tracer):
         with obs_trace.span("s", a=1) as sp:
             sp.set(b=2)
-        ((_, _, _, _, _, attrs),) = obs_trace.events()
+        ((_, _, _, _, _, attrs, *_),) = _spans()
         assert attrs == {"a": 1, "b": 2}
 
     def test_thread_safety_many_threads(self, tracer):
@@ -82,11 +87,11 @@ class TestSpanTracer:
         for t in threads:
             t.join(timeout=30)
         assert not any(t.is_alive() for t in threads)
-        evs = obs_trace.events()
+        evs = _spans()
         assert len(evs) == n_threads * per_thread * 2
-        for name, _, _, _, depth, _ in evs:
+        for name, _, _, _, depth, *_ in evs:
             assert depth == (1 if name == "t.inner" else 0)
-        assert len({tid for _, _, _, tid, _, _ in evs}) == n_threads
+        assert len({e[3] for e in evs}) == n_threads
 
     def test_aot_pool_compile_spans(self, tracer):
         """The precompile pipeline's per-signature compile spans are
@@ -103,7 +108,7 @@ class TestSpanTracer:
             pipe.wait_all(timeout=60)
         finally:
             pipe.shutdown()
-        spans = [e for e in obs_trace.events() if e[0] == "aot.compile"]
+        spans = [e for e in _spans() if e[0] == "aot.compile"]
         assert len(spans) == 1
         assert spans[0][5]["sig"] == "obs_test"
         assert spans[0][3] != threading.get_ident(), "span must be on a pool thread"
@@ -114,9 +119,13 @@ class TestSpanTracer:
             for i in range(20):
                 with obs_trace.span(f"s{i}"):
                     pass
+            # each root span brings its clock anchor: enable's anchor, then
+            # (anchor, span) per root — 41 events, the newest 8 survive
             evs = obs_trace.events()
-            assert [e[0] for e in evs] == [f"s{i}" for i in range(12, 20)]
-            assert obs_trace.dropped() == 12
+            assert [e[0] for e in evs] == [
+                n for i in range(16, 20) for n in ("obs.clock", f"s{i}")
+            ]
+            assert obs_trace.dropped() == 33
             # timestamps stay chronological across the wrap
             ts = [e[1] for e in evs]
             assert ts == sorted(ts)
@@ -139,7 +148,7 @@ class TestSpanTracer:
         assert complete[0]["args"]["pods"] == 3
         assert isinstance(complete[0]["ts"], int)
         assert complete[0]["dur"] >= 1
-        instants = [e for e in events if e["ph"] == "i"]
+        instants = [e for e in events if e["ph"] == "i" and e["name"] != "obs.clock"]
         assert len(instants) == 1 and instants[0]["name"] == "mark"
         # thread-name metadata rides along for the Perfetto lane labels
         assert any(e["ph"] == "M" and e["name"] == "thread_name" for e in events)
@@ -473,3 +482,261 @@ class TestCLIObs:
         doc = json.load(open(tpath))
         names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
         assert {"tensorize", "expand", "schedule.cluster"} <= names
+
+
+class TestSpanIdentity:
+    """Span ids, parents and roots (docs/observability.md §1): the
+    causality the self-time partition and the per-answer readings are
+    built on, across threads and into the Chrome export."""
+
+    @staticmethod
+    def _by_name():
+        return {e[0]: e for e in _spans()}
+
+    def test_nested_parent_and_root_ids(self, tracer):
+        with obs_trace.span("answer"):
+            with obs_trace.span("stage"):
+                with obs_trace.span("step"):
+                    pass
+            obs_trace.instant("mark")
+        evs = self._by_name()
+        ans, stage, step, mark = (evs[n] for n in ("answer", "stage", "step", "mark"))
+        sids = {e[6] for e in (ans, stage, step, mark)}
+        assert len(sids) == 4, "every event gets its own id"
+        assert ans[7] is None and ans[8] == ans[6]
+        assert (stage[7], stage[8]) == (ans[6], ans[6])
+        assert (step[7], step[8]) == (stage[6], ans[6])
+        assert (mark[7], mark[8]) == (ans[6], ans[6])
+        assert [e[4] for e in (ans, stage, step)] == [0, 1, 2]
+
+    def test_root_span_records_clock_anchor(self, tracer):
+        with obs_trace.span("answer"):
+            with obs_trace.span("inner"):
+                pass
+        anchors = [e for e in obs_trace.events() if e[0] == "obs.clock"]
+        # one at enable(), one at the start of the one root span
+        assert len(anchors) == 2
+        root = self._by_name()["answer"]
+        assert anchors[1][8] == root[6]
+        assert set(anchors[1][5]) == {"ts_ns", "wall_ns"}
+
+    def test_adopted_thread_inherits_parent_and_root(self, tracer):
+        """Work handed to another thread keeps the submitting span as
+        parent and root, captured at submit time, not at run time."""
+        with obs_trace.span("answer"):
+            with obs_trace.span("submit"):
+                ctx = obs_trace.current()
+            # the worker runs after `submit` closed: the captured id holds
+
+            def work():
+                with obs_trace.adopt(ctx):
+                    with obs_trace.span("worker"):
+                        with obs_trace.span("worker.inner"):
+                            pass
+                with obs_trace.span("unrelated"):
+                    pass
+
+            t = threading.Thread(target=work)
+            t.start()
+            t.join(timeout=30)
+        evs = self._by_name()
+        ans, sub = evs["answer"], evs["submit"]
+        worker, inner = evs["worker"], evs["worker.inner"]
+        assert worker[3] != ans[3], "recorded on the worker thread"
+        assert (worker[7], worker[8]) == (sub[6], ans[6])
+        assert (inner[7], inner[8]) == (worker[6], ans[6])
+        assert worker[4] == 0 and inner[4] == 1
+        # after adopt() exits the thread's spans are roots of their own
+        assert evs["unrelated"][7] is None and evs["unrelated"][8] == evs["unrelated"][6]
+
+    def test_current_and_adopt_are_free_when_off(self):
+        obs_trace.disable()
+        assert obs_trace.current() is None
+        assert obs_trace.adopt(None) is obs_trace.span("x")
+
+    def test_aot_pool_span_parent_is_submitting_candidate(self, tracer):
+        """A real AotPipeline: the pool thread's compile span names the
+        candidate span that enumerated it as parent, and its answer as
+        root."""
+        import jax
+        import jax.numpy as jnp
+
+        from simtpu.engine.precompile import AotPipeline, _sds
+
+        pipe = AotPipeline(workers=2)
+        try:
+            with obs_trace.span("apply"):
+                with obs_trace.span("plan.candidate", count=3):
+                    fn = jax.jit(lambda x: x + 7)
+                    assert pipe.submit("obs_cause", (), fn, (_sds((6,), jnp.int32),))
+                pipe.wait_all(timeout=60)
+        finally:
+            pipe.shutdown()
+        evs = self._by_name()
+        comp, cand, root = evs["aot.compile"], evs["plan.candidate"], evs["apply"]
+        assert comp[3] != cand[3], "compiled on a pool thread"
+        assert comp[7] == cand[6] and comp[8] == root[6]
+
+    def test_chrome_export_carries_parent_root_and_anchors(self, tracer, tmp_path):
+        with obs_trace.span("outer"):
+            with obs_trace.span("inner"):
+                pass
+        doc = json.load(open(obs_trace.export_trace(str(tmp_path / "t.json"))))
+        spans = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+        outer, inner = spans["outer"]["args"], spans["inner"]["args"]
+        assert outer["parent"] is None and outer["root"] == inner["root"]
+        assert inner["parent"] == outer["root"]
+        anchors = doc["otherData"]["clock_anchors"]
+        assert len(anchors) == 2 and all(len(a) == 2 for a in anchors)
+        clocks = [e for e in doc["traceEvents"] if e["name"] == "obs.clock"]
+        assert [[c["args"]["ts_ns"], c["args"]["wall_ns"]] for c in clocks] == anchors
+
+    def test_profiler_ns_monotone_across_anchors(self, tracer, monkeypatch):
+        import time
+
+        for _ in range(4):
+            with obs_trace.span("answer"):
+                time.sleep(0.002)
+        anchors = list(obs_trace._ANCHORS)
+        assert len(anchors) == 5
+        # at an anchor the map reads the anchor's own wall clock
+        for ts_ns, wall_ns in anchors:
+            assert abs(obs_trace.profiler_ns(ts_ns / 1000) - wall_ns) <= 1
+        lo, hi = anchors[0][0] / 1000 - 5000, anchors[-1][0] / 1000 + 5000
+        grid = [lo + (hi - lo) * i / 997 for i in range(998)]
+        mapped = [obs_trace.profiler_ns(t) for t in grid]
+        assert mapped == sorted(mapped)
+        # offsets that wander between anchors still map monotonically
+        jitter = [(a, w + (-40_000 if i % 2 else 40_000)) for i, (a, w) in
+                  enumerate((i * 1_000_000, 5_000_000_000 + i * 1_000_000) for i in range(6))]
+        monkeypatch.setattr(obs_trace, "_ANCHORS", jitter)
+        mapped = [obs_trace.profiler_ns(t) for t in range(-500, 6500, 7)]
+        assert mapped == sorted(mapped)
+
+    def test_profiler_ns_none_without_anchors(self):
+        obs_trace.disable()
+        assert obs_trace.profiler_ns(123.0) is None
+
+
+class TestJitEvents:
+    """JAX's compile duration events (obs/profile.py): always into the
+    `jit.*_s` histograms, and into the ring as spans while tracing."""
+
+    NAMES = ("jit.trace_s", "jit.lower_s", "jit.compile_s")
+
+    def test_fresh_jit_yields_one_of_each_then_none(self, tracer):
+        import jax
+
+        from simtpu.obs.profile import install_jit_listener
+
+        install_jit_listener()
+
+        def obs_jit_probe(x):
+            return jax.lax.add(jax.lax.mul(x, x), x)
+
+        f = jax.jit(obs_jit_probe)
+        arg = np.arange(7, dtype=np.int32)
+
+        def counts():
+            return {n: REGISTRY.value(n, default={"count": 0})["count"] for n in self.NAMES}
+
+        def jit_spans():
+            return [e for e in _spans() if e[0].startswith("jit.")
+                    and "obs_jit_probe" in (e[5] or {}).get("fun", "")]
+
+        before = counts()
+        with obs_trace.span("caller"):
+            np.asarray(f(arg))
+        caller = {e[0]: e for e in _spans()}["caller"]
+        spans = jit_spans()
+        assert sorted(e[0] for e in spans) == ["jit.compile", "jit.lower", "jit.trace"]
+        for e in spans:
+            assert e[7] == caller[6] and e[8] == caller[6]
+            assert e[2] >= 1 and caller[1] <= e[1]
+        assert {n: counts()[n] - before[n] for n in self.NAMES} == dict.fromkeys(self.NAMES, 1)
+
+        mid = counts()
+        np.asarray(f(arg + 1))  # the same shape: the executable is cached
+        assert len(jit_spans()) == 3
+        assert counts() == mid
+
+    def test_listener_installs_once(self):
+        import jax
+
+        from simtpu.obs import profile
+
+        profile.install_jit_listener()
+        n = len(jax._src.monitoring._event_duration_secs_listeners)
+        profile.install_jit_listener()
+        from simtpu.cache import enable_compilation_cache
+
+        enable_compilation_cache()
+        assert len(jax._src.monitoring._event_duration_secs_listeners) == n
+
+
+def _write_fixture(root, n_nodes=3, replicas=5):
+    """A simon config over `n_nodes` nodes and one deployment, written as
+    one manifest document each; returns (config path, documents)."""
+    import yaml
+
+    os.makedirs(root / "cluster")
+    os.makedirs(root / "app")
+
+    def node(name):
+        res = {"cpu": "8", "memory": "32Gi", "pods": "110"}
+        return {"apiVersion": "v1", "kind": "Node",
+                "metadata": {"name": name, "labels": {"kubernetes.io/hostname": name}},
+                "status": {"allocatable": res, "capacity": res}}
+
+    nodes = [node(f"n{i}") for i in range(n_nodes)]
+    dep = {"apiVersion": "apps/v1", "kind": "Deployment",
+           "metadata": {"name": "web", "namespace": "default"},
+           "spec": {"replicas": replicas,
+                    "selector": {"matchLabels": {"app": "web"}},
+                    "template": {"metadata": {"labels": {"app": "web"}},
+                                 "spec": {"containers": [{
+                                     "name": "web", "image": "web:1",
+                                     "resources": {"requests": {"cpu": "1", "memory": "1Gi"}}}]}}}}
+    with open(root / "cluster" / "nodes.yaml", "w") as f:
+        yaml.safe_dump_all(nodes, f)
+    with open(root / "app" / "web.yaml", "w") as f:
+        yaml.safe_dump(dep, f)
+    with open(root / "newnode.yaml", "w") as f:
+        yaml.safe_dump(node("tmpl"), f)
+    config = root / "simon.yaml"
+    with open(config, "w") as f:
+        yaml.safe_dump({"apiVersion": "simon/v1alpha1", "kind": "Config",
+                        "metadata": {"name": "obs"},
+                        "spec": {"cluster": {"customConfig": str(root / "cluster")},
+                                 "appList": [{"name": "web", "path": str(root / "app")}],
+                                 "newNode": str(root / "newnode.yaml")}}, f)
+    return str(config), n_nodes + 2
+
+
+@pytest.mark.parametrize("search", ["binary", "incremental"])
+def test_apply_json_records_layer_spans(tmp_path, capsys, search):
+    """One `apply --json` answer records a span for every host layer
+    (decode, objects, expand, tensorize, materialize, report) under the
+    root `apply`, and counts the documents it decoded."""
+    from simtpu.cli import main
+
+    config, n_docs = _write_fixture(tmp_path)
+    obs_trace.enable()
+    try:
+        before = REGISTRY.snapshot()
+        rc = main(["apply", "-f", config, "--json", "--search", search])
+        delta = REGISTRY.delta_since(before)
+        evs = _spans()
+    finally:
+        obs_trace.disable()
+    doc = json.loads(capsys.readouterr().out)
+    assert rc == 0 and doc["success"] is True
+    (root,) = [e for e in evs if e[0] == "apply"]
+    assert root[7] is None
+    names = {e[0] for e in evs if e[8] == root[6]}
+    assert {"ingest.decode", "ingest.objects", "expand", "tensorize",
+            "plan.materialize", "report"} <= names
+    assert delta["ingest.docs"] == n_docs
+    assert delta["ingest.bytes"] == sum(
+        os.path.getsize(p) for p in glob.glob(str(tmp_path / "**" / "*.yaml"), recursive=True)
+        if not p.endswith("simon.yaml"))
