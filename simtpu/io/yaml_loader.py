@@ -10,7 +10,7 @@ Mirrors the reference's file-walking + decode pipeline:
 from __future__ import annotations
 
 import os
-from typing import List
+from typing import List, Union
 
 import yaml
 
@@ -71,10 +71,19 @@ def get_yaml_content_from_directory(path: str) -> List[str]:
     return docs
 
 
-def decode_yaml_content(text: str) -> List[dict]:
-    """Split a (possibly multi-document) YAML string into object dicts."""
+#: libyaml's C parser where PyYAML was built with it (several times
+#: faster on large manifests), else the pure-Python one; both feed the
+#: same `SafeConstructor`, so the objects are identical
+_LOADER = getattr(yaml, "CSafeLoader", yaml.SafeLoader)
+
+
+def decode_yaml_content(text: Union[str, bytes]) -> List[dict]:
+    """Split a (possibly multi-document) YAML text, str or UTF-8 bytes,
+    into object dicts."""
+    if isinstance(text, str):
+        text = str(text)  # the C parser refuses str subclasses (`SourcedText`)
     objs = []
-    for doc in yaml.safe_load_all(text):
+    for doc in yaml.load_all(text, Loader=_LOADER):
         if isinstance(doc, dict) and doc.get("kind"):
             objs.append(doc)
     return objs
@@ -88,14 +97,18 @@ def get_objects_from_yaml_content(docs: List[str]) -> ResourceTypes:
 
     Every text is decoded first, then the objects are built, so the two
     stages are two spans (`ingest.decode`, `ingest.objects`); the
-    `ingest.docs` / `ingest.bytes` counters give decode its rate."""
+    `ingest.docs` / `ingest.bytes` counters give decode its rate, and
+    `ingest.libyaml_docs` the documents libyaml decoded."""
     from ..workloads.expand import SOURCE_KEY
 
     with span("ingest.decode", texts=len(docs)):
-        decoded = [decode_yaml_content(text) for text in docs]
+        raw = [text.encode() for text in docs]
+        decoded = [decode_yaml_content(b) for b in raw]
     n_docs = sum(len(objs) for objs in decoded)
     REGISTRY.counter("ingest.docs").inc(n_docs)
-    REGISTRY.counter("ingest.bytes").inc(sum(len(t.encode()) for t in docs))
+    REGISTRY.counter("ingest.bytes").inc(sum(len(b) for b in raw))
+    if _LOADER is not yaml.SafeLoader:
+        REGISTRY.counter("ingest.libyaml_docs").inc(n_docs)
     resources = ResourceTypes()
     with span("ingest.objects", docs=n_docs):
         for text, objs in zip(docs, decoded):

@@ -457,9 +457,11 @@ class TestCLIObs:
         sums = {}
         for x in complete:
             sums[x["name"]] = sums.get(x["name"], 0.0) + x["dur"] / 1e6
+        # (the --json timings keep milliseconds: an ingest of a few ms is
+        # reconciled to that last digit)
         for phase in ("ingest", "plan"):
             span_s, json_s = sums[phase], doc["timings"][phase]
-            assert span_s == pytest.approx(json_s, rel=0.05), phase
+            assert span_s == pytest.approx(json_s, rel=0.05, abs=1e-3), phase
         # the engine layers all reported in: dispatch chunks, audit
         names = set(sums)
         assert {"tensorize", "expand", "audit.pass"} <= names
