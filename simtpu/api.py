@@ -48,6 +48,7 @@ from .engine.scan import (
     REASON_TEXT,
     Engine,
 )
+from .obs.metrics import REGISTRY
 from .obs.trace import span
 
 # Failure classes where evicting lower-priority pods can help — the analog of
@@ -426,6 +427,18 @@ class Simulator:
 
         if not failed:
             return
+        lowest = min(self._placed_prio, default=None)
+        if lowest is None or all(pod_priority(p) <= lowest for p, _ in failed):
+            # no failed pod outranks a placed one: nothing to preempt
+            for pod, reason in failed:
+                self._record_failed(pod, reason)
+            return
+        # the preempt.* counters (docs/observability.md): `victims` counts
+        # exactly the evictions committed to `self._preempted`
+        count = {
+            k: REGISTRY.counter(f"preempt.{k}")
+            for k in ("waves", "preemptors", "victims", "demoted", "final_failures")
+        }
         # (pod, reason, saved victim records or None, fresh-retry used)
         pending = [(pod, reason, None, False) for pod, reason in failed]
         # heads already granted the affinity-dependence finality deferral
@@ -441,207 +454,232 @@ class Simulator:
         # (WAVE_CAP_SLACK is an attribute so tests can force the abort path)
         waves_left = self.WAVE_CAP_SLACK + 2 * len(failed)
         while pending:
-            waves_left -= 1
-            if waves_left < 0:
-                # termination-insurance abort: these pods were still PENDING
-                # (the serial evict/retry order might yet have placed them),
-                # so their original failure reason is stale — tag it so a
-                # tripped cap is observable, and say how many pods it cut off
-                log.warning(
-                    "preemption wave cap exhausted with %d pod(s) still "
-                    "pending; recording them unscheduled with their original "
-                    "failure reasons",
-                    len(pending),
-                )
-                n_aborted = len(pending)
-                for pod, reason, preev, _ in pending:
-                    if preev:
-                        self._restore_victims(preev)
-                    self._record_failed(
-                        pod,
-                        reason,
-                        note=(
-                            f"{PREEMPT_WAVE_CAP_NOTE}, "
-                            f"{n_aborted} pod(s) unresolved"
-                        ),
+            with span("preempt.wave", pods=len(pending)):
+                count["waves"].inc()
+                waves_left -= 1
+                if waves_left < 0:
+                    # termination-insurance abort: these pods were still PENDING
+                    # (the serial evict/retry order might yet have placed them),
+                    # so their original failure reason is stale — tag it so a
+                    # tripped cap is observable, and say how many pods it cut off
+                    log.warning(
+                        "preemption wave cap exhausted with %d pod(s) still "
+                        "pending; recording them unscheduled with their original "
+                        "failure reasons",
+                        len(pending),
                     )
-                return
-            model = self._build_preempt_model()
-            wave = []  # (pod, reason, new victims, prior records, retried)
-            for pod, reason, preev, retried in pending:
-                if preev is not None:
-                    # evicted in an earlier wave; only re-verification left
-                    wave.append((pod, reason, [], preev, retried))
-                    continue
-                victims = self._propose_victims(pod, reason, model)
-                if victims is None:
-                    self._record_failed(pod, reason)
-                else:
-                    wave.append((pod, reason, victims, None, retried))
-            if not wave:
-                return
-            owner = {}
-            for w, (_, _, victims, _, _) in enumerate(wave):
-                for i in victims:
-                    owner[i] = w
-            saved_per_pod = [
-                list(preev) if preev is not None else []
-                for (_, _, _, preev, _) in wave
-            ]
-            all_v = sorted(owner)
-            if all_v:
-                saved = self._engine.remove_placements(all_v)
-                for i, entry in zip(saved["indices"], saved["entries"]):
-                    saved_per_pod[owner[i]].append(
-                        (
-                            entry,
-                            self._scheduled[i],
-                            self._placed_prio[i],
-                            self._placed_forced[i],
+                    n_aborted = len(pending)
+                    count["final_failures"].inc(n_aborted)
+                    for pod, reason, preev, _ in pending:
+                        if preev:
+                            self._restore_victims(preev)
+                        self._record_failed(
+                            pod,
+                            reason,
+                            note=(
+                                f"{PREEMPT_WAVE_CAP_NOTE}, "
+                                f"{n_aborted} pod(s) unresolved"
+                            ),
                         )
-                    )
-                for i in reversed(saved["indices"]):
-                    del self._scheduled[i]
-                    del self._placed_prio[i]
-                    del self._placed_forced[i]
-            probe = self._tensorizer.add_pods([p for p, _, _, _, _ in wave])
-            log_base = len(self._engine.placed_node)
-            nodes, _, extras = self._engine.place(probe)
-            nodes = np.asarray(nodes)
-            placed_mask = nodes >= 0
-            fail_pos = np.flatnonzero(~placed_mask)
-            f = int(fail_pos[0]) if len(fail_pos) else len(wave)
-            ranks = np.cumsum(placed_mask) - 1  # log rank of each placed pod
-            # A pod before f may have verify-landed on a placement that only
-            # passed because of f's (about to be restored) evictions — the
-            # batched placement saw ALL wave evictions, not just the pod's
-            # own.  Committing it while restoring f's victims would silently
-            # violate an invariant the serial evict/retry/undo flow never
-            # can: node resource overcommit (pod sits on a victim's node),
-            # a required anti-affinity or DoNotSchedule-spread verdict that
-            # flips when the victims return (domain-scoped — demote when the
-            # pod's node shares a relevant topology domain with a victim's
-            # node), or a restored victim's own required anti-affinity now
-            # matching the new pod (same domain test, victim's keys).
-            # Demote those pods instead: skip their commit, drop their log
-            # entries, and re-verify them next wave with their own evictions
-            # kept (advisor finding, round 4).
-            # An eviction is PERMANENT only once its proposer commits.  The
-            # victims of f (restored this wave), of after-f pods, and of
-            # demoted pods (carried as preev, restorable in a LATER wave or
-            # the cap-abort path) are all provisional — so the demote scan
-            # runs to a fixpoint: demoting a pod makes its own victims
-            # provisional too.
-            demote: set = set()
-            if f < len(wave):
-
-                def _labels(idx: int) -> dict:
-                    meta = self._nodes[idx].get("metadata") or {}
-                    return meta.get("labels") or {}
-
-                prov_nodes: set = set()
-                prov_victims: list = []
-
-                def _absorb(records):
-                    for entry, vpod, _prio, _forced in records:
-                        prov_nodes.add(entry[1])
-                        prov_victims.append(
-                            (_labels(entry[1]), _anti_topo_keys(vpod))
-                        )
-
-                for w in range(f, len(wave)):
-                    _absorb(saved_per_pod[w])
-                # hoisted per-pod spec parses / label lookups: the fixpoint
-                # below rescans range(f) once per demotion
-                w_node = [int(nodes[w]) for w in range(f)]
-                w_keys = [_restore_topo_keys(wave[w][0]) for w in range(f)]
-                w_labels = [_labels(n) for n in w_node]
-                changed = True
-                while changed:
-                    changed = False
-                    for w in range(f):
-                        if w in demote:
+                    return
+                with span("preempt.propose", pods=len(pending)):
+                    model = self._build_preempt_model()
+                    wave = []  # (pod, reason, new victims, prior records, retried)
+                    for pod, reason, preev, retried in pending:
+                        if preev is not None:
+                            # evicted in an earlier wave; only re-verification left
+                            wave.append((pod, reason, [], preev, retried))
                             continue
-                        rides = w_node[w] in prov_nodes
-                        if not rides:
-                            wl = w_labels[w]
-                            rides = any(
-                                k in wl and k in vl and wl[k] == vl[k]
-                                for vl, vkeys in prov_victims
-                                for k in (*w_keys[w], *vkeys)
+                        victims = self._propose_victims(pod, reason, model)
+                        if victims is None:
+                            self._record_failed(pod, reason)
+                            count["final_failures"].inc()
+                        else:
+                            wave.append((pod, reason, victims, None, retried))
+                if not wave:
+                    return
+                owner = {}
+                for w, (_, _, victims, _, _) in enumerate(wave):
+                    for i in victims:
+                        owner[i] = w
+                saved_per_pod = [
+                    list(preev) if preev is not None else []
+                    for (_, _, _, preev, _) in wave
+                ]
+                all_v = sorted(owner)
+                with span("preempt.evict", pods=len(all_v)):
+                    if all_v:
+                        saved = self._engine.remove_placements(all_v)
+                        for i, entry in zip(saved["indices"], saved["entries"]):
+                            saved_per_pod[owner[i]].append(
+                                (
+                                    entry,
+                                    self._scheduled[i],
+                                    self._placed_prio[i],
+                                    self._placed_forced[i],
+                                )
                             )
-                        if rides:
-                            demote.add(w)
-                            _absorb(saved_per_pod[w])
-                            changed = True
-            for w in range(f):
-                if w in demote:
-                    continue
-                pod = wave[w][0]
-                who = f"{namespace_of(pod)}/{name_of(pod)}"
-                for _, vpod, _prio, _forced in saved_per_pod[w]:
-                    self._preempted.append(
-                        PreemptedPod(
-                            pod=vpod,
-                            preempted_by=who,
-                            node=vpod["spec"].get("nodeName", ""),
+                        for i in reversed(saved["indices"]):
+                            del self._scheduled[i]
+                            del self._placed_prio[i]
+                            del self._placed_forced[i]
+                with span("preempt.verify", pods=len(wave)):
+                    probe = self._tensorizer.add_pods([p for p, _, _, _, _ in wave])
+                    log_base = len(self._engine.placed_node)
+                    nodes, _, extras = self._engine.place(probe)
+                    nodes = np.asarray(nodes)
+                placed_mask = nodes >= 0
+                fail_pos = np.flatnonzero(~placed_mask)
+                f = int(fail_pos[0]) if len(fail_pos) else len(wave)
+                ranks = np.cumsum(placed_mask) - 1  # log rank of each placed pod
+                # A pod before f may have verify-landed on a placement that only
+                # passed because of f's (about to be restored) evictions — the
+                # batched placement saw ALL wave evictions, not just the pod's
+                # own.  Committing it while restoring f's victims would silently
+                # violate an invariant the serial evict/retry/undo flow never
+                # can: node resource overcommit (pod sits on a victim's node),
+                # a required anti-affinity or DoNotSchedule-spread verdict that
+                # flips when the victims return (domain-scoped — demote when the
+                # pod's node shares a relevant topology domain with a victim's
+                # node), or a restored victim's own required anti-affinity now
+                # matching the new pod (same domain test, victim's keys).
+                # Demote those pods instead: skip their commit, drop their log
+                # entries, and re-verify them next wave with their own evictions
+                # kept (advisor finding, round 4).
+                # An eviction is PERMANENT only once its proposer commits.  The
+                # victims of f (restored this wave), of after-f pods, and of
+                # demoted pods (carried as preev, restorable in a LATER wave or
+                # the cap-abort path) are all provisional — so the demote scan
+                # runs to a fixpoint: demoting a pod makes its own victims
+                # provisional too.
+                demote: set = set()
+                if f < len(wave):
+
+                    def _labels(idx: int) -> dict:
+                        meta = self._nodes[idx].get("metadata") or {}
+                        return meta.get("labels") or {}
+
+                    prov_nodes: set = set()
+                    prov_victims: list = []
+
+                    def _absorb(records):
+                        for entry, vpod, _prio, _forced in records:
+                            prov_nodes.add(entry[1])
+                            prov_victims.append(
+                                (_labels(entry[1]), _anti_topo_keys(vpod))
+                            )
+
+                    for w in range(f, len(wave)):
+                        _absorb(saved_per_pod[w])
+                    # hoisted per-pod spec parses / label lookups: the fixpoint
+                    # below rescans range(f) once per demotion
+                    w_node = [int(nodes[w]) for w in range(f)]
+                    w_keys = [_restore_topo_keys(wave[w][0]) for w in range(f)]
+                    w_labels = [_labels(n) for n in w_node]
+                    changed = True
+                    while changed:
+                        changed = False
+                        for w in range(f):
+                            if w in demote:
+                                continue
+                            rides = w_node[w] in prov_nodes
+                            if not rides:
+                                wl = w_labels[w]
+                                rides = any(
+                                    k in wl and k in vl and wl[k] == vl[k]
+                                    for vl, vkeys in prov_victims
+                                    for k in (*w_keys[w], *vkeys)
+                                )
+                            if rides:
+                                demote.add(w)
+                                _absorb(saved_per_pod[w])
+                                changed = True
+                count["demoted"].inc(len(demote))
+                commit = [w for w in range(f) if w not in demote]
+                # the verify places each preemptor wherever the real pipeline
+                # scores best among ALL the wave's freed nodes, not always on
+                # the node its own victims left (equal-score freed nodes tie):
+                # a victim is credited to the committed preemptor that landed
+                # on its node — the preemptor that node's evictions made room
+                # for — where that one outranks it, else to its proposer
+                landed: dict = {}
+                for w in commit:
+                    landed.setdefault(int(nodes[w]), w)
+                who = [
+                    f"{namespace_of(p)}/{name_of(p)}" for p, _, _, _, _ in wave
+                ]
+                for w in commit:
+                    pod = wave[w][0]
+                    for entry, vpod, vprio, _forced in saved_per_pod[w]:
+                        o = landed.get(int(entry[1]), w)
+                        if pod_priority(wave[o][0]) <= vprio:
+                            o = w
+                        self._preempted.append(
+                            PreemptedPod(
+                                pod=vpod,
+                                preempted_by=who[o],
+                                node=vpod["spec"].get("nodeName", ""),
+                            )
                         )
+                    count["victims"].inc(len(saved_per_pod[w]))
+                    count["preemptors"].inc()
+                    self._record_placed(pod, int(nodes[w]), extras["gpu_shares"][w])
+                if f == len(wave):
+                    return
+                # demoted pods and pods after f placed against a state that is
+                # about to change (f's victims return) — revert their log
+                # entries; they re-verify next wave
+                revert = [
+                    log_base + int(ranks[w])
+                    for w in list(demote) + list(range(f + 1, len(wave)))
+                    if placed_mask[w]
+                ]
+                with span("preempt.restore", pods=len(saved_per_pod[f])):
+                    if revert:
+                        self._engine.remove_placements(revert)  # permanent, no undo
+                    self._restore_victims(saved_per_pod[f])
+                pod_f, reason_f, _, preev_f, retried_f = wave[f]
+                # retry-finality exemption (ADVICE r5 #3): a fresh-retried head
+                # whose required positive affinity selects another wave pod is
+                # NOT finalized — the head verifies first in its wave, so its
+                # verdict never saw that pod placed, and the serial evict/retry
+                # order could still place both.  The exempted head re-queues
+                # BEHIND the pods it depends on (deliberately trading the
+                # victim-node re-grab protection below for the chance that the
+                # anchor pod lands first); termination stays bounded by the
+                # wave cap.
+                affinity_dependent = id(pod_f) not in affinity_deferred and (
+                    _head_affinity_depends_on(
+                        pod_f, [wave[w][0] for w in range(len(wave)) if w != f]
                     )
-                self._record_placed(pod, int(nodes[w]), extras["gpu_shares"][w])
-            if f == len(wave):
-                return
-            # demoted pods and pods after f placed against a state that is
-            # about to change (f's victims return) — revert their log
-            # entries; they re-verify next wave
-            revert = [
-                log_base + int(ranks[w])
-                for w in list(demote) + list(range(f + 1, len(wave)))
-                if placed_mask[w]
-            ]
-            if revert:
-                self._engine.remove_placements(revert)  # permanent, no undo
-            self._restore_victims(saved_per_pod[f])
-            pod_f, reason_f, _, preev_f, retried_f = wave[f]
-            # retry-finality exemption (ADVICE r5 #3): a fresh-retried head
-            # whose required positive affinity selects another wave pod is
-            # NOT finalized — the head verifies first in its wave, so its
-            # verdict never saw that pod placed, and the serial evict/retry
-            # order could still place both.  The exempted head re-queues
-            # BEHIND the pods it depends on (deliberately trading the
-            # victim-node re-grab protection below for the chance that the
-            # anchor pod lands first); termination stays bounded by the
-            # wave cap.
-            affinity_dependent = id(pod_f) not in affinity_deferred and (
-                _head_affinity_depends_on(
-                    pod_f, [wave[w][0] for w in range(len(wave)) if w != f]
                 )
-            )
-            if affinity_dependent and retried_f and preev_f is None:
-                # this exemption actually skipped finality — consume the
-                # pod's one deferral (ordering-only moves don't)
-                affinity_deferred.add(id(pod_f))
-            if retried_f and preev_f is None and not affinity_dependent:
-                # the failed attempt was a FRESH proposal against the true
-                # wave-start log state — the verify verdict is
-                # serial-authoritative.  (A retried pod failing a
-                # preev-carried MID-WAVE re-verify — it was demoted after
-                # its fresh attempt placed — is NOT final: its victims were
-                # just restored, so it re-proposes fresh next wave.)
-                self._record_failed(pod_f, reason_f)
-                head = []
-            else:
-                head = [(pod_f, reason_f, None, True)]
-            # the retried head verifies FIRST: a demoted pod verifying ahead
-            # of it could re-grab the head's victim node (wave evictions
-            # apply before every verify), wrongly finalizing the head's
-            # failure; demoted pods re-verify right after, before after-f
-            # pods, keeping their relative serial order.  (Exception: an
-            # affinity-dependent head queues LAST, see above.)
-            rest = [
-                (wave[w][0], wave[w][1], saved_per_pod[w], wave[w][4])
-                for w in [*sorted(demote), *range(f + 1, len(wave))]
-            ]
-            pending = rest + head if affinity_dependent else head + rest
+                if affinity_dependent and retried_f and preev_f is None:
+                    # this exemption actually skipped finality — consume the
+                    # pod's one deferral (ordering-only moves don't)
+                    affinity_deferred.add(id(pod_f))
+                if retried_f and preev_f is None and not affinity_dependent:
+                    # the failed attempt was a FRESH proposal against the true
+                    # wave-start log state — the verify verdict is
+                    # serial-authoritative.  (A retried pod failing a
+                    # preev-carried MID-WAVE re-verify — it was demoted after
+                    # its fresh attempt placed — is NOT final: its victims were
+                    # just restored, so it re-proposes fresh next wave.)
+                    self._record_failed(pod_f, reason_f)
+                    count["final_failures"].inc()
+                    head = []
+                else:
+                    head = [(pod_f, reason_f, None, True)]
+                # the retried head verifies FIRST: a demoted pod verifying ahead
+                # of it could re-grab the head's victim node (wave evictions
+                # apply before every verify), wrongly finalizing the head's
+                # failure; demoted pods re-verify right after, before after-f
+                # pods, keeping their relative serial order.  (Exception: an
+                # affinity-dependent head queues LAST, see above.)
+                rest = [
+                    (wave[w][0], wave[w][1], saved_per_pod[w], wave[w][4])
+                    for w in [*sorted(demote), *range(f + 1, len(wave))]
+                ]
+                pending = rest + head if affinity_dependent else head + rest
 
     def _restore_victims(self, records) -> None:
         """Re-insert evicted victims (a failed preemptor's) at the END of

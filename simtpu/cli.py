@@ -84,6 +84,11 @@ def _plan_json(plan, resilience: dict = None) -> str:
         "unscheduled": (
             len(plan.result.unscheduled_pods) if plan.result is not None else None
         ),
+        # the shipped answer's preemption victims (simulate()'s evictions;
+        # the incremental planner never preempts, so 0 there)
+        "preempted": (
+            len(plan.result.preempted_pods) if plan.result is not None else None
+        ),
     }
     if isinstance(doc.get("engine"), dict):
         # grow.* counter family (append-only vocabulary growth): zero for
@@ -382,6 +387,11 @@ def _cmd_apply(args: argparse.Namespace) -> int:
         with span("report"):
             print(report(plan.result.node_status, opts.extended_resources))
         print(C.COLOR_RESET, end="")
+        if plan.result.preempted_pods:
+            print(
+                f"preempted {len(plan.result.preempted_pods)} lower-priority "
+                "pod(s) to place the pods that outrank them"
+            )
         if plan.audit:
             from .report import audit_report
 
@@ -393,7 +403,7 @@ def _cmd_apply(args: argparse.Namespace) -> int:
             print(solve_report(plan.solve))
         if getattr(plan, "preemption_ignored", False):
             print(
-                f"{C.COLOR_YELLOW}warning: specs carry pod priorities, but "
+                f"{C.COLOR_YELLOW}warning: pod priorities differ, but "
                 "the incremental planner never runs preemption — "
                 "priority/eviction semantics were IGNORED (use --search "
                 f"binary/linear for the preemption path){C.COLOR_RESET}"

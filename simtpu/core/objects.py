@@ -13,7 +13,7 @@ from __future__ import annotations
 import copy
 import json
 from dataclasses import dataclass, field
-from typing import Dict, Iterator, List
+from typing import Dict, Iterable, Iterator, List
 
 from .quantity import parse_quantity
 
@@ -365,6 +365,14 @@ def pod_priority(pod: dict) -> float:
         return float(p)
     name = pod_spec(pod).get("priorityClassName") or ""
     return _BUILTIN_PRIORITY_CLASSES.get(name, 0.0)
+
+
+def can_preempt(pending: Iterable[float], others: Iterable[float]) -> bool:
+    """Whether DefaultPreemption can fire: some pod still to be scheduled
+    (priorities `pending`) outranks another pod, pending or placed
+    (`others`). Uniform priorities, and none, can never preempt."""
+    pending = list(pending)
+    return bool(pending) and max(pending) > min([*pending, *others])
 
 
 def pod_tolerations(pod: dict) -> List[dict]:

@@ -40,9 +40,12 @@ from ..core.objects import (
     AppResource,
     ResourceTypes,
     SimulateResult,
+    can_preempt,
     name_of,
     namespace_of,
+    pod_priority,
     pod_requests,
+    pod_spec,
     set_label,
 )
 from ..core.quantity import parse_quantity
@@ -120,8 +123,8 @@ class PlanResult:
     # not consulted (--no-solver / SIMTPU_SOLVER unset); rides --json
     # under engine.solve
     solve: Dict[str, object] = field(default_factory=dict)
-    # True when the incremental planner received priority/preemption-
-    # bearing specs: probes never run preemption (capacity planning asks
+    # True when the incremental planner received specs whose pods can
+    # preempt: probes never run preemption (capacity planning asks
     # whether everything fits), so priority semantics were IGNORED — the
     # loud runtime counterpart of the docs/status.md note; rides --json
     # under engine.preemption_ignored
@@ -788,6 +791,24 @@ def _declared_pod_estimate(cluster: ResourceTypes, apps: Sequence[AppResource]) 
     return one(cluster, n) + sum(one(a.resource, n) for a in apps)
 
 
+def _declared_preemption(cluster: ResourceTypes, apps: Sequence[AppResource]) -> bool:
+    """Whether the declared pods and workload templates can preempt, read
+    without expansion: a pod still to be scheduled (an unbound pod, or any
+    workload's template) outranks another declared pod."""
+    pending: List[float] = []
+    bound: List[float] = []
+    for res in [cluster, *(a.resource for a in apps)]:
+        for p in res.pods:
+            (bound if pod_spec(p).get("nodeName") else pending).append(pod_priority(p))
+        for w in (res.deployments + res.replica_sets + res.replication_controllers
+                  + res.stateful_sets + res.jobs + res.daemon_sets):
+            pending.append(pod_priority((w.get("spec") or {}).get("template") or {}))
+        for cj in res.cron_jobs:
+            job = ((cj.get("spec") or {}).get("jobTemplate") or {}).get("spec") or {}
+            pending.append(pod_priority(job.get("template") or {}))
+    return can_preempt(pending, bound)
+
+
 def _resolve_engines(
     opts: ApplierOptions,
     cluster: ResourceTypes,
@@ -804,14 +825,26 @@ def _resolve_engines(
     n_nodes = len(cluster.nodes)
     est_pods = _declared_pod_estimate(cluster, apps)
     large = n_nodes >= AUTO_ENGINE_NODES or est_pods >= AUTO_ENGINE_PODS
-    search = opts.search if opts.search is not None else ("incremental" if large else "binary")
+    # the incremental planner never preempts: where pods can, only the
+    # searches through simulate() give their answer, at any size
+    preempt = large and opts.search is None and _declared_preemption(cluster, apps)
+    search = opts.search if opts.search is not None else (
+        "incremental" if large and not preempt else "binary")
     bulk = opts.bulk if opts.bulk is not None else large
     if large and (opts.search is None or opts.bulk is None):
+        why = (
+            "; pods can preempt (their priorities differ), so the search "
+            "runs simulate()'s preemption — pass --search incremental to "
+            "plan without it, or --no-bulk for the serial reference-exact "
+            "engines"
+            if preempt
+            else "; pass --search binary/linear or --no-bulk for the serial "
+            "reference-exact engines"
+        )
         print(
             f"simtpu: large problem ({n_nodes} nodes, ~{est_pods} declared "
             f"pods) — auto-selected {'bulk' if bulk else 'serial'} placement"
-            f" + {search} search; pass --search binary/linear or --no-bulk "
-            "for the serial reference-exact engines",
+            f" + {search} search{why}",
             file=sys.stderr,
         )
     mesh = None
@@ -1159,7 +1192,7 @@ class Applier:
             "solve": plan.solve if plan.solve else {"enabled": False},
             # loud runtime flag (docs/status.md): the incremental
             # planner's probes never run preemption, and this plan's
-            # specs carried pod priorities — they were ignored
+            # pods could have preempted — their priorities were ignored
             "preemption_ignored": bool(
                 getattr(plan, "preemption_ignored", False)
             ),

@@ -404,17 +404,22 @@ def _plan_capacity_incremental(
     timings["tensorize"] = time.perf_counter() - t0
 
     # -- loud no-preemption notice (docs/status.md): probes never evict.
-    # Capacity planning asks whether everything FITS — priority-bearing
-    # specs plan fine, but their eviction semantics are ignored, and
-    # that must be visible at runtime, not only in the docs.
-    from ..core.objects import pod_priority
+    # Capacity planning asks whether everything FITS — where pods could
+    # preempt (a pod to schedule outranks another), their eviction
+    # semantics are ignored, and that must be visible at runtime, not
+    # only in the docs.  Uniform priorities can never preempt.
+    from ..core.objects import can_preempt, pod_priority, pod_spec
 
-    if any(pod_priority(p) != 0 for p in ordered):
+    prios = [pod_priority(p) for p in ordered]
+    if can_preempt(
+        (q for p, q in zip(ordered, prios) if not pod_spec(p).get("nodeName")),
+        prios,
+    ):
         import sys
 
         preempt_flag[0] = True
         notice = (
-            "simtpu: specs carry pod priorities, but the incremental "
+            "simtpu: pod priorities differ, but the incremental "
             "planner never runs preemption — priority/eviction semantics "
             "are IGNORED (use --search binary/linear for simulate()'s "
             "preemption path)"

@@ -742,3 +742,59 @@ def test_apply_json_records_layer_spans(tmp_path, capsys, search):
     assert delta["ingest.bytes"] == sum(
         os.path.getsize(p) for p in glob.glob(str(tmp_path / "**" / "*.yaml"), recursive=True)
         if not p.endswith("simon.yaml"))
+
+
+class TestPreemptionSpans:
+    """The preemption waves' spans and counters (docs/observability.md):
+    `preempt.wave` under `schedule.app`, its phases under it, and
+    `preempt.victims` equal to the victims the result reports."""
+
+    PHASES = ("preempt.propose", "preempt.evict", "preempt.verify")
+
+    @staticmethod
+    def _simulate(priorities: bool):
+        from simtpu.api import simulate
+        from simtpu.core.objects import AppResource, ResourceTypes
+
+        from .fixtures import make_fake_node, make_fake_pod
+
+        def pod(name, cpu, prio):
+            p = make_fake_pod(name, "default", cpu, "500Mi")
+            if priorities:
+                p["spec"]["priority"] = prio
+            return p
+
+        cluster = ResourceTypes(
+            nodes=[make_fake_node(f"n{i}", "4", "32Gi") for i in range(2)],
+            pods=[pod(f"low-{i}", "900m", 1) for i in range(8)],
+        )
+        app = AppResource(name="high", resource=ResourceTypes(
+            pods=[pod(f"high-{i}", "3", 10) for i in range(2)]))
+        return simulate(cluster, [app])
+
+    def test_waves_nest_under_the_app_and_count_the_victims(self, tracer):
+        before = REGISTRY.snapshot()
+        result = self._simulate(priorities=True)
+        delta = REGISTRY.delta_since(before)
+        evs = _spans()
+        assert len(result.preempted_pods) == 6 and not result.unscheduled_pods
+        by_id = {e[6]: e for e in evs}
+        (app,) = [e for e in evs if e[0] == "schedule.app"]
+        waves = [e for e in evs if e[0] == "preempt.wave"]
+        assert waves and all(w[7] == app[6] for w in waves)
+        for name in self.PHASES:
+            spans = [e for e in evs if e[0] == name]
+            assert spans, name
+            assert all(by_id[e[7]][0] == "preempt.wave" for e in spans)
+        assert delta["preempt.victims"] == len(result.preempted_pods)
+        assert delta["preempt.waves"] >= 1
+        assert delta["preempt.preemptors"] == 2
+        assert delta["preempt.final_failures"] == 0
+
+    def test_no_priorities_record_no_preemption(self, tracer):
+        before = REGISTRY.snapshot()
+        result = self._simulate(priorities=False)
+        delta = REGISTRY.delta_since(before)
+        assert len(result.unscheduled_pods) == 2 and not result.preempted_pods
+        assert not [e for e in _spans() if e[0].startswith("preempt.")]
+        assert not any(v for k, v in delta.items() if k.startswith("preempt."))

@@ -394,6 +394,46 @@ class TestAutoEngines:
         )
         assert (search, bulk) == ("incremental", True)
 
+    @staticmethod
+    def _large_prioritized(low: int, high: int):
+        """A large cluster with one bound pod of priority `low`, and an app
+        whose pods have priority `high`."""
+        from simtpu.plan.capacity import AUTO_ENGINE_NODES
+
+        cluster = ResourceTypes()
+        cluster.nodes = [
+            make_fake_node(f"n{i}", "4", "8Gi") for i in range(AUTO_ENGINE_NODES)
+        ]
+        bound = make_fake_pod("batch", "default", "1", "1Gi")
+        bound["spec"].update(nodeName="n0", priority=low)
+        cluster.pods = [bound]
+        app = _app(3)
+        app.resource.deployments[0]["spec"]["template"]["spec"]["priority"] = high
+        return cluster, app
+
+    @pytest.mark.parametrize("low,high,search", [
+        (1, 10, "binary"),  # the app's pods outrank the bound pod: preemption
+        (5, 5, "incremental"),  # uniform priority: nothing can preempt
+        (10, 1, "incremental"),  # only the bound pod outranks: nothing pending can
+    ])
+    def test_preemption_selects_the_search_that_preempts(self, capsys, low, high, search):
+        from simtpu.plan.capacity import ApplierOptions, _resolve_engines
+
+        cluster, app = self._large_prioritized(low, high)
+        got, bulk, _ = _resolve_engines(ApplierOptions(), cluster, [app])
+        assert (got, bulk) == (search, True)
+        err = capsys.readouterr().err
+        assert "auto-selected bulk placement" in err
+        assert ("pods can preempt" in err) == (search == "binary")
+
+    def test_explicit_incremental_keeps_it_where_pods_preempt(self, capsys):
+        from simtpu.plan.capacity import ApplierOptions, _resolve_engines
+
+        cluster, app = self._large_prioritized(1, 10)
+        opts = ApplierOptions(search="incremental")
+        assert _resolve_engines(opts, cluster, [app])[:2] == ("incremental", True)
+        assert "pods can preempt" not in capsys.readouterr().err
+
     def test_explicit_flags_override_auto(self, capsys):
         from simtpu.plan.capacity import AUTO_ENGINE_PODS, ApplierOptions, _resolve_engines
 

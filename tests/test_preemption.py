@@ -608,3 +608,115 @@ def test_preemption_under_compact_rides_direct_delta(monkeypatch):
     assert p_direct == p_ab
     assert e_direct == e_ab
     assert u_direct == u_ab
+
+
+# -- kube-scheduler's PreemptionBasic through `simtpu apply`'s default path --
+#
+# The benchmark's generator (benchmark/gen/sched_perf_preempt.py) writes the
+# shape at a few nodes; the answer, reduced to counts, is checked by the
+# plain reference (benchmark/reference/preempt.py), which imports nothing of
+# simtpu. AUTO_ENGINE_NODES is lowered below the node count so that
+# auto-selection takes its large-problem branch, as at 5,000 nodes.
+
+_LOW = {"cpu_m": 900, "mem_mib": 500}
+_HIGH = {"name": "pod-high-priority", "cpu_m": 3000, "mem_mib": 500}
+
+
+def _preempt_basic_cfg(nodes: int, tiers=(1,), high_priority: int = 10) -> dict:
+    per_tier = 4 * nodes // len(tiers)
+    low = [dict(_LOW, name=f"pod-low-priority{'' if len(tiers) == 1 else p}",
+                pods=per_tier, priority=p) for p in tiers]
+    return {"nodes": nodes, "node_template": {"cpu": 4, "mem_gib": 32, "pods": 110},
+            "low": low, "measure": dict(_HIGH, pods=nodes, priority=high_priority)}
+
+
+def _apply_preempt_basic(tmp_path, monkeypatch, capsys, cfg, *flags):
+    """(problem, --json document, stderr, captured PlanResult) of one
+    `simtpu apply -f <problem> --json` answer."""
+    import json
+
+    from benchmark.gen import sched_perf_preempt
+    from simtpu import cli
+    from simtpu.plan import capacity
+
+    problem = sched_perf_preempt.build(cfg, 4294967311)
+    config = problem.write(str(tmp_path))
+    monkeypatch.setattr(capacity, "AUTO_ENGINE_NODES", cfg["nodes"] // 2)
+    plans = []
+    run = capacity.Applier.run
+
+    def capture(self, *a, **kw):
+        plans.append(run(self, *a, **kw))
+        return plans[-1]
+
+    monkeypatch.setattr(capacity.Applier, "run", capture)
+    capsys.readouterr()
+    rc = cli.main(["apply", "-f", config, "--json", *flags])
+    out, err = capsys.readouterr()
+    doc = json.loads(out.strip().splitlines()[-1])
+    assert rc == (0 if doc["success"] else 1)
+    return problem, doc, err, plans[-1]
+
+
+def _reference_numbers(problem, doc, plan) -> dict:
+    from benchmark.drivers.batch_answer import reduce
+    from benchmark.drivers.preempt_answer import reduce_victims
+    from benchmark.reference import preempt as ref
+
+    placed, unscheduled, clones = reduce(plan, {n.name for n in problem.node_specs})
+    victims = reduce_victims(plan)
+    _, _, want = ref.expected(problem.node_specs, problem.groups, problem.priority)
+    nums = ref.check(problem.node_specs, problem.template_spec, doc["nodes_added"],
+                     problem.groups, problem.priority, placed, unscheduled,
+                     victims, want)
+    nums["answer_mismatch"] = (
+        abs(doc["nodes_added"] - clones)
+        + abs(doc["unscheduled"] - sum(unscheduled.values()))
+        + abs(doc["preempted"] - sum(victims.values())))
+    return nums
+
+
+@pytest.mark.parametrize("nodes,tiers", [(8, (1,)), (32, (1,)), (64, (1,)), (32, (1, 5))],
+                         ids=["8", "32", "64", "32-two-tiers"])
+def test_preemption_basic_default_apply_matches_reference(
+        tmp_path, monkeypatch, capsys, nodes, tiers):
+    """Every high pod evicts exactly three low pods on its own node (the
+    lowest tier first), nothing is left unscheduled and no node is added:
+    the reference's every number reads 0, and `preempted` is 3 x nodes."""
+    problem, doc, err, plan = _apply_preempt_basic(
+        tmp_path, monkeypatch, capsys, _preempt_basic_cfg(nodes, tiers))
+    assert doc["engine"]["search"] == "binary" and doc["engine"]["bulk"] is True
+    assert "pods can preempt" in err and "IGNORED" not in err
+    assert doc["success"] is True and doc["nodes_added"] == 0
+    assert doc["unscheduled"] == 0
+    assert doc["preempted"] == 3 * nodes
+    assert doc["engine"]["audit"]["ok"] is True
+    assert _reference_numbers(problem, doc, plan) == dict.fromkeys(
+        ("overcommit", "lost_pods", "unscheduled", "victim_priority",
+         "reprievable", "victims_wrong", "answer_mismatch"), 0)
+
+
+def test_preemption_basic_explicit_incremental_ignores_preemption(
+        tmp_path, monkeypatch, capsys):
+    """`--search incremental` keeps its old answer: no evictions, clones up
+    to the search's cap of 100, the rest unscheduled, and the notice."""
+    problem, doc, err, plan = _apply_preempt_basic(
+        tmp_path, monkeypatch, capsys, _preempt_basic_cfg(128), "--search", "incremental")
+    assert doc["engine"]["search"] == "incremental"
+    assert doc["engine"]["preemption_ignored"] is True
+    assert "IGNORED" in err
+    assert doc["success"] is False and doc["preempted"] is None
+    assert min(doc["probes"].values()) > 0
+
+
+def test_preemption_basic_uniform_priority_keeps_incremental(
+        tmp_path, monkeypatch, capsys):
+    """Where every pod has the same priority nothing can preempt: the large
+    problem keeps the incremental search, with no IGNORED notice."""
+    _, doc, err, _ = _apply_preempt_basic(
+        tmp_path, monkeypatch, capsys, _preempt_basic_cfg(8, (7,), high_priority=7))
+    assert doc["engine"]["search"] == "incremental"
+    assert doc["engine"]["preemption_ignored"] is False
+    assert "IGNORED" not in err and "pods can preempt" not in err
+    assert doc["success"] is True and doc["nodes_added"] == 8
+    assert doc["preempted"] == 0

@@ -322,9 +322,13 @@ class TestPreemptionWarning:
     the machine-readable flag; clean specs stay silent."""
 
     def _priority_problem(self):
+        # the notice fires where pods could preempt: priorities that differ
         cluster, apps, template = _small_plan_problem()
         dep = apps[0].resource.deployments[0]
         dep["spec"]["template"]["spec"]["priority"] = 100
+        apps[0].resource.deployments.append(
+            make_fake_deployment("batch", "default", 1, "1", "1Gi")
+        )
         return cluster, apps, template
 
     def test_priority_specs_raise_the_ignored_notice(self, capsys):
