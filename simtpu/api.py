@@ -10,8 +10,9 @@ is one compiled scan.
 
 from __future__ import annotations
 
+import heapq
 import logging
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 from . import constants as C
 from .core.objects import (
@@ -139,6 +140,164 @@ def _sort_app_pods(pods: List[dict], nodes: Sequence[dict] = (), use_greed: bool
     if use_greed:
         pods = greed_sort(pods, nodes)
     return toleration_sort(affinity_sort(pods))
+
+
+class _PodClass:
+    """What the victim search reads of a failed pod. `key` is (group id,
+    pinned node index — -1 for none, -2 for a node that does not exist —,
+    priority, request row bytes, GPU need, LVM need); `req` the row."""
+
+    __slots__ = ("key", "gid", "pin", "prio", "gpu_need", "lvm_need", "req")
+
+    def __init__(self, key: tuple, req):
+        self.key = key
+        self.gid, self.pin, self.prio, _, self.gpu_need, self.lvm_need = key
+        self.req = req
+
+
+class _Segments:
+    """The runs of one node index in a sorted candidate list: each run's
+    first row, node and last row, and each row's run and position in it."""
+
+    __slots__ = ("first", "node", "id", "pos", "last", "_levels")
+
+    def __init__(self, nodes_sorted):
+        import numpy as np
+
+        start = np.ones(len(nodes_sorted), bool)
+        start[1:] = nodes_sorted[1:] != nodes_sorted[:-1]
+        self.first = np.flatnonzero(start)
+        self.node = nodes_sorted[self.first]
+        self.id = np.cumsum(start) - 1
+        self.pos = np.arange(len(start)) - self.first[self.id]
+        self.last = np.append(start[1:], True)
+        self._levels = None
+
+    def scan(self, ufunc, vals):
+        """Inclusive `ufunc` scan of `vals` (rows in run order) within each
+        run: row k of a run combines rows 0..k of that run, left to right,
+        and nothing else, so a run's result does not depend on the runs
+        around it (a whole-array cumsum less the run's base would round
+        with the magnitude of everything before it)."""
+        import numpy as np
+
+        if len(self.first) == 1:
+            return ufunc.accumulate(vals, axis=0)
+        if self._levels is None:
+            by_pos = np.argsort(self.pos, kind="stable")
+            self._levels = np.split(by_pos, np.cumsum(np.bincount(self.pos))[:-1])[1:]
+        out = vals.copy()
+        for at in self._levels:  # the rows at position k of their run
+            out[at] = ufunc(out[at - 1], vals[at])
+        return out
+
+
+class _SegmentSearch(NamedTuple):
+    """`_victim_segments`' answer, per node segment: `rows` holds every
+    segment's candidates in eviction order, segment s from `first[s]`;
+    `count[s]` is its minimal qualifying prefix (0: none qualifies) and
+    `viol`, `top`, `total` the prefix's PDB violations, highest and summed
+    victim priority."""
+
+    rows: object
+    node: object
+    first: object
+    count: object
+    viol: object
+    top: object
+    total: object
+
+
+class _VictimTable:
+    """One (pod class, failure reason)'s victim search over a wave.
+
+    Built by a cold whole-log pass, answered first straight off its arrays;
+    from the second answer on, the valid segments' pickOneNode keys sit on
+    a heap, and a node a proposal debited is searched again and pushed with
+    a new version, so older entries of that node are dropped as they
+    surface. `seen` is how much of the model's dirty-node log is applied."""
+
+    __slots__ = ("cls", "relevant", "node_ok", "seen", "cold", "heap", "version", "fresh", "cold_seg")
+
+    def __init__(self, cls, relevant, node_ok, seen: int, cold):
+        self.cls = cls
+        self.relevant = relevant  # per log entry
+        self.node_ok = node_ok  # per node: a landing site for the class
+        self.seen = seen
+        self.cold = cold
+        self.heap = None
+        self.version: dict = {}  # node -> refreshes so far
+        self.fresh: dict = {}  # node -> victims of its latest search, or None
+        self.cold_seg: dict = {}  # node -> its valid segment of `cold`
+
+    def candidates(self, model: dict, node: int):
+        """The node's candidate log entries, ascending, as the cold pass
+        selects them."""
+        bounds = model["node_bounds"]
+        rows = model["node_rows"][bounds[node] : bounds[node + 1]]
+        if not self.node_ok[node]:
+            return rows[:0]
+        return rows[(model["prios"][rows] < self.cls.prio) & self.relevant[rows]]
+
+    def cold_best(self):
+        """(node, victims) of the cold pass's best segment, or None."""
+        import numpy as np
+
+        s = self.cold
+        if s is None or not s.count.any():
+            return None
+        b = int(np.lexsort((s.node, s.count, s.total, s.top, s.viol, s.count == 0))[0])
+        return int(s.node[b]), s.rows[s.first[b] : s.first[b] + s.count[b]].tolist()
+
+    def _heap_form(self) -> None:
+        import numpy as np
+
+        self.heap = []
+        s = self.cold
+        if s is None:
+            return
+        v = np.flatnonzero(s.count)
+        nodes = s.node[v].tolist()
+        self.cold_seg = dict(zip(nodes, v.tolist()))
+        self.heap = list(
+            zip(
+                s.viol[v].tolist(),
+                s.top[v].tolist(),
+                s.total[v].tolist(),
+                s.count[v].tolist(),
+                nodes,
+                [0] * len(nodes),
+            )
+        )
+        heapq.heapify(self.heap)
+
+    def update(self, node: int, search) -> None:
+        """Replace `node`'s segment by `search`, its search alone."""
+        if self.heap is None:
+            self._heap_form()
+        ver = self.version[node] = self.version.get(node, 0) + 1
+        if search is None or not search.count[0]:
+            self.fresh[node] = None
+            return
+        n = int(search.count[0])
+        self.fresh[node] = search.rows[:n].tolist()
+        key = (int(search.viol[0]), float(search.top[0]), float(search.total[0]), n)
+        heapq.heappush(self.heap, (*key, node, ver))
+
+    def best(self):
+        """(node, victims) of the best valid segment, or None."""
+        if self.heap is None:
+            self._heap_form()
+        heap = self.heap
+        while heap:
+            node, ver = heap[0][4:]
+            if ver == self.version.get(node, 0):
+                if node in self.fresh:
+                    return node, self.fresh[node]
+                s, b = self.cold, self.cold_seg[node]
+                return node, s.rows[s.first[b] : s.first[b] + s.count[b]].tolist()
+            heapq.heappop(heap)
+        return None
 
 
 class Simulator:
@@ -304,7 +463,7 @@ class Simulator:
         # index-parallel with the engine's placement log (Engine.place logged
         # the whole batch already); preemption then runs against a consistent
         # view — the analog of failed pods re-entering via the backoff queue
-        failed = []
+        failed, rows = [], []
         with span("schedule.record", pods=len(batch.pods)):
             for i, (pod, node_idx, reason) in enumerate(
                 zip(batch.pods, nodes, reasons)
@@ -316,16 +475,19 @@ class Simulator:
                     )
                 else:
                     failed.append((pod, int(reason)))
-        self._preempt_failed_batch(failed)
+                    rows.append(i)
+        self._preempt_failed_batch(failed, batch, rows)
 
     # -- preemption (DefaultPreemption PostFilter analog) -------------------
 
     def _build_preempt_model(self) -> dict:
         """Whole-log host arrays shared by a preemption WAVE: priorities,
-        per-entry node/request/extended usage, and per-node usage sums.
-        Built once per wave (O(log)) and updated incrementally per victim
-        proposal — the r3 implementation rebuilt all of it per preemption,
-        which dominated at 10^5-entry logs (VERDICT r3 weak #1)."""
+        per-entry node/request/extended usage, per-node usage sums, the log
+        entries of each node, and the wave's PDBs. Built once per wave
+        (O(log)) and updated incrementally per victim proposal — the r3
+        implementation rebuilt all of it per preemption, which dominated at
+        10^5-entry logs (VERDICT r3 weak #1). `dirty` logs the node each
+        proposal debits; `tables` holds the wave's `_VictimTable`s."""
         import numpy as np
 
         tz = self._tensorizer
@@ -369,7 +531,10 @@ class Simulator:
         np.add.at(gpu_used_n, placed_nodes, gpu_use_log)
         vg_used_n = np.zeros(n_nodes, np.float32)
         np.add.at(vg_used_n, placed_nodes, vg_use_log)
+        node_rows = np.argsort(placed_nodes, kind="stable")
+        ext = tz.ext
         return {
+            # an evicted entry's priority is +inf: it never qualifies again
             "prios": np.asarray(self._placed_prio, np.float64),
             "placed_nodes": placed_nodes,
             "placed_req": placed_req,
@@ -381,10 +546,27 @@ class Simulator:
             "sd_any_log": sd_any_log,
             "gpu_used_n": gpu_used_n,
             "vg_used_n": vg_used_n,
-            "evicted": np.zeros(m, bool),
+            "gpu_cap": ext.gpu_dev_total.sum(axis=1),
+            "vg_cap": ext.vg_cap.sum(axis=1) - ext.vg_req0.sum(axis=1),
+            # node n's entries, ascending: node_rows[node_bounds[n]:node_bounds[n + 1]]
+            "node_rows": node_rows,
+            "node_bounds": np.searchsorted(
+                placed_nodes[node_rows], np.arange(n_nodes + 1)
+            ),
+            "pdbs": [
+                (
+                    namespace_of(p),
+                    (p.get("spec") or {}).get("selector"),
+                    int(((p.get("status") or {}).get("disruptionsAllowed")) or 0),
+                )
+                for p in self._pdbs
+            ],
+            "pdb_rows": {},  # log entry -> the PDBs covering it
+            "dirty": [],
+            "tables": {},  # (reason, class key) -> _VictimTable
         }
 
-    def _preempt_failed_batch(self, failed) -> None:
+    def _preempt_failed_batch(self, failed, batch, rows) -> None:
         """Preempt for a whole batch of failed pods with BATCHED device work.
 
         Mirrors the DefaultPreemption flow per pod — find candidate nodes
@@ -422,7 +604,8 @@ class Simulator:
         verifies, so optimism (e.g. two preemptors counting the same free
         CPU) self-corrects exactly like the serial evict/retry/undo did.
         Victims are reported in `SimulateResult.preempted_pods`, not
-        re-queued."""
+        re-queued. `failed` holds (pod, reason) for the rows `rows` of
+        `batch`."""
         import numpy as np
 
         if not failed:
@@ -434,11 +617,16 @@ class Simulator:
                 self._record_failed(pod, reason)
             return
         # the preempt.* counters (docs/observability.md): `victims` counts
-        # exactly the evictions committed to `self._preempted`
+        # exactly the evictions committed to `self._preempted`; `tables` and
+        # `refreshes` are counted where the victim search does that work
         count = {
             k: REGISTRY.counter(f"preempt.{k}")
-            for k in ("waves", "preemptors", "victims", "demoted", "final_failures")
+            for k in (
+                "waves", "preemptors", "victims", "demoted", "final_failures",
+                "tables", "refreshes",
+            )
         }
+        cls_of = None  # id(pod) -> _PodClass, read in the first proposals
         # (pod, reason, saved victim records or None, fresh-retry used)
         pending = [(pod, reason, None, False) for pod, reason in failed]
         # heads already granted the affinity-dependence finality deferral
@@ -483,6 +671,9 @@ class Simulator:
                         )
                     return
                 with span("preempt.propose", pods=len(pending)):
+                    if cls_of is None:
+                        classes = self._victim_classes(batch, rows)
+                        cls_of = {id(pod): c for (pod, _), c in zip(failed, classes)}
                     model = self._build_preempt_model()
                     wave = []  # (pod, reason, new victims, prior records, retried)
                     for pod, reason, preev, retried in pending:
@@ -490,7 +681,7 @@ class Simulator:
                             # evicted in an earlier wave; only re-verification left
                             wave.append((pod, reason, [], preev, retried))
                             continue
-                        victims = self._propose_victims(pod, reason, model)
+                        victims = self._propose_victims(cls_of[id(pod)], reason, model)
                         if victims is None:
                             self._record_failed(pod, reason)
                             count["final_failures"].inc()
@@ -700,310 +891,82 @@ class Simulator:
             self._placed_prio.append(vprio)
             self._placed_forced.append(vforced)
 
-    def _propose_victims(self, pod: dict, reason: int, model: dict):
-        """Host-side victim proposal for one failed pod against the wave
-        model; returns wave-start log indices of the victims (and debits
-        them from the model so later proposals see the eviction), or None
-        when no plausible set exists. Victim greed prefers PDB-free pods
-        (lowest priority first, most recent first on ties) the way the
-        reference reprieves PDB-violating victims preferentially
-        (selectVictimsOnNode, default_preemption.go:639-668), and the
-        violation count follows filterPodsWithPDBViolation's budget
+    def _victim_classes(self, batch, rows) -> list:
+        """The `_PodClass` of each failed pod `batch.pods[i]`, `i` in
+        `rows`, read off the batch's tensorized rows, so the search runs no
+        `add_pods` of its own; pods of one class share one object."""
+        import numpy as np
+
+        tz = self._tensorizer
+        r = tz.alloc.shape[1]
+        ext = batch.ext
+        out, seen = [], {}
+        for i in rows:
+            pod = batch.pods[i]
+            pin = int(batch.pin[i])
+            if batch.forced[i]:
+                # a bound pod's row pins its nodeName; the search pins by
+                # required node affinity alone
+                _, pin_name = _group_of_pod(pod)
+                pin = -1 if pin_name is None else tz.node_idx.get(pin_name, -2)
+            req = np.zeros(r, np.float32)
+            req[: batch.req.shape[1]] = batch.req[i]
+            key = (
+                int(batch.group[i]),
+                pin,
+                pod_priority(pod),
+                req.tobytes(),
+                float(ext["gpu_mem"][i]) * max(float(ext["gpu_count"][i]), 1.0),
+                float(ext["lvm_size"][i].sum(dtype=np.float64)),
+            )
+            cls = seen.get(key)
+            if cls is None:
+                cls = seen[key] = _PodClass(key, req)
+            out.append(cls)
+        return out
+
+    def _propose_victims(self, cls: "_PodClass", reason: int, model: dict):
+        """Host-side victim proposal for one failed pod of class `cls`
+        against the wave model; returns wave-start log indices of the
+        victims (and debits them from the model so later proposals see the
+        eviction), or None when no plausible set exists. Victim greed
+        prefers PDB-free pods (lowest priority first, most recent first on
+        ties) the way the reference reprieves PDB-violating victims
+        preferentially (selectVictimsOnNode, default_preemption.go:639-668),
+        and the violation count follows filterPodsWithPDBViolation's budget
         accounting: each matching victim decrements the PDB's
         disruptionsAllowed, violating once it goes negative. The simulation
         runs no disruption controller, so the budget is
         `status.disruptionsAllowed` as ingested (absent = 0, like the
-        reference's fake cluster)."""
-        import numpy as np
+        reference's fake cluster).
 
-        from .core.objects import labels_of
+        A proposal reads the rest of the cluster only through per-node
+        segments, and debits one node. So the first proposal of a (class,
+        reason) in a wave searches the whole log once (`_victim_table`),
+        and each later one searches again only the nodes that proposals
+        debited since (`model["dirty"]`): the same answer as a whole-log
+        search, at the cost of one node's pods."""
+        import numpy as np
 
         if reason not in _PREEMPTIBLE_REASONS or not len(model["prios"]):
             return None
-        prio = pod_priority(pod)
-        prios = np.where(model["evicted"], np.inf, model["prios"])
-        placed_nodes = model["placed_nodes"]
-        if not np.any(prios < prio):
-            return None
-        tz = self._tensorizer
-        g, pin_name = _group_of_pod(pod)
-        gid = tz._group_ids.get(g.signature())
-        if gid is None:
-            return None
-        static = tz._static_mask[gid]
-        alloc = tz.alloc
-        r = alloc.shape[1]
-
-        def padded(row):
-            return np.pad(row, (0, r - row.shape[0])) if row.shape[0] < r else row
-
-        placed_req = model["placed_req"]
-        used = model["used"]
-        pod_req = padded(self._pod_req_vector(pod))
-
-        # per-reason victim relevance + plausibility (the retry verifies)
-        pod_ports = set(tz._port_rows[gid].keys())
-        anti_terms = {t for t, v in tz._a_anti[gid].items() if v}
-        spread_terms = {t for t, v in tz._spread_hard[gid].items() if v > 0}
-        pod_conflict_keys = set(tz._vol_rw_rows[gid]) | set(tz._vol_ro_rows[gid])
-        pod_att_classes = {
-            tz._vol_class[w] for w in tz._vol_att_rows[gid] if w in tz._vol_class
-        }
-        probe = tz.add_pods([pod])
-        gpu_need = float(probe.ext["gpu_mem"][0]) * max(
-            float(probe.ext["gpu_count"][0]), 1.0
-        )
-        lvm_need = float(np.sum(probe.ext["lvm_size"][0]))
-
-        # PDB bookkeeping (filterPodsWithPDBViolation semantics): a PDB with
-        # a nil or EMPTY selector matches nothing here — unlike the general
-        # LabelSelector rule — and unlabeled pods match no PDB (upstream
-        # short-circuits on `len(pod.Labels) != 0`,
-        # default_preemption.go:745-746, even though a DoesNotExist selector
-        # would otherwise match them; parity kept deliberately)
-        pdb_list = [
-            (
-                namespace_of(p),
-                (p.get("spec") or {}).get("selector"),
-                int(((p.get("status") or {}).get("disruptionsAllowed")) or 0),
-            )
-            for p in self._pdbs
-        ]
-        _pdb_cache: dict = {}
-
-        def pdbs_matching(i: int) -> tuple:
-            got = _pdb_cache.get(i)
-            if got is None:
-                from .core.match import match_label_selector
-
-                victim = self._scheduled[i]
-                labels = labels_of(victim)
-                got = tuple(
-                    j
-                    for j, (ns, sel, _) in enumerate(pdb_list)
-                    if labels
-                    and ns == namespace_of(victim)
-                    and sel
-                    and (sel.get("matchLabels") or sel.get("matchExpressions"))
-                    and match_label_selector(sel, labels)
-                )
-                _pdb_cache[i] = got
-            return got
-
-        def pdb_violations(victim_idx) -> int:
-            """How many victims push a matching PDB's budget negative."""
-            allowed = [a for (_, _, a) in pdb_list]
-            count = 0
-            for i in victim_idx:
-                violated = False
-                for j in pdbs_matching(i):
-                    allowed[j] -= 1
-                    if allowed[j] < 0:
-                        violated = True
-                count += violated
-            return count
-
-        # ---- vectorized victim search -----------------------------------
-        # The per-node Python loop this replaces cost O(nodes × placed) per
-        # failed pod — unusable against 10^5-node clusters with 10^6-entry
-        # placement logs (VERDICT r2 task 5). Everything below is whole-log
-        # numpy: candidate relevance by reason, the PDB reprieve split, the
-        # greedy per-node eviction prefix, and the pickOneNode key all
-        # evaluate per placement-log ENTRY over sorted node segments.
-        n_nodes = len(self._nodes)
-        placed_groups_a = model["placed_groups"]
-        g_count = len(tz.groups)
-
-        # victim relevance per reason, at group granularity where possible
-        if reason == FAIL_PORTS:
-            rel_g = np.array(
-                [bool(pod_ports & set(tz._port_rows[vg].keys())) for vg in range(g_count)]
-            )
-            relevant = rel_g[placed_groups_a]
-        elif reason == FAIL_INTERPOD:
-            rel_g = np.array(
-                [any(tz._s_match[vg].get(t) for t in anti_terms) for vg in range(g_count)]
-            )
-            relevant = rel_g[placed_groups_a]
-        elif reason == FAIL_SPREAD:
-            rel_g = np.array(
-                [any(tz._s_match[vg].get(t) for t in spread_terms) for vg in range(g_count)]
-            )
-            relevant = rel_g[placed_groups_a]
-        elif reason == FAIL_VOLUME:
-            # the victim must hold one of the conflicting volume identities
-            # via a rw/ro mount — attach-only usage (resolved PVC
-            # attachables) cannot cause a VolumeRestrictions conflict
-            rel_g = np.array(
-                [
-                    bool(
-                        pod_conflict_keys
-                        & (set(tz._vol_rw_rows[vg]) | set(tz._vol_ro_rows[vg]))
-                    )
-                    for vg in range(g_count)
-                ]
-            )
-            relevant = rel_g[placed_groups_a]
-        elif reason == FAIL_ATTACH:
-            # evicting any holder of a same-class attachable frees a slot
-            rel_g = np.array(
-                [
-                    bool(
-                        pod_att_classes
-                        & {
-                            tz._vol_class[w]
-                            for w in set(tz._vol_att_rows[vg]) | set(tz._vol_rw_rows[vg])
-                            if w in tz._vol_class
-                        }
-                    )
-                    for vg in range(g_count)
-                ]
-            )
-            relevant = rel_g[placed_groups_a]
-        elif reason == FAIL_GPU:
-            relevant = model["gpu_mem_log"] > 0
-        elif reason == FAIL_STORAGE:
-            relevant = (model["vg_use_log"] > 0) | model["sd_any_log"]
-        else:  # FAIL_RESOURCES: any eviction frees resources
-            relevant = np.ones(len(placed_groups_a), bool)
-
-        node_ok = np.asarray(static, bool).copy()
-        if getattr(self._engine, "node_valid", None) is not None:
-            # fault-masked nodes (simtpu/faults/drain.py) are not landing
-            # sites: the engine's filter pipeline is guaranteed to reject
-            # them at verify, so proposing one only burns a wave
-            node_ok &= np.asarray(self._engine.node_valid, bool)
-        if pin_name is not None:
-            # the pin restricts WITHIN the static mask (the serial loop
-            # checked static first): a pinned node the pod can never place
-            # on must not trigger a doomed evict/retry/restore round-trip
-            pin_idx = tz.node_idx.get(pin_name, -1)
-            keep = node_ok[pin_idx] if pin_idx >= 0 else False
-            node_ok[:] = False
-            if keep:
-                node_ok[pin_idx] = True
-        cand_mask = (prios < prio) & relevant & node_ok[placed_nodes]
-        cand = np.flatnonzero(cand_mask)
-        if not len(cand):
-            return None
-        c_nodes = placed_nodes[cand]
-        c_prios = prios[cand]
-
-        # PDB reprieve split (filterPodsWithPDBViolation): walk each node's
-        # candidates in MoreImportantPod order (priority desc, index asc)
-        # decrementing budgets; a victim is VIOLATING once a matching PDB's
-        # budget goes negative. Vectorized as per-(pdb, node) running counts
-        # along the sorted order.
-        j_pdbs = len(pdb_list)
-        violating1 = np.zeros(len(cand), bool)
-        pdb_match_c = None
-        if j_pdbs:
-            pdb_match_c = np.zeros((j_pdbs, len(cand)), bool)
-            for ci, i in enumerate(cand):
-                for j in pdbs_matching(int(i)):
-                    pdb_match_c[j, ci] = True
-            order1 = np.lexsort((cand, -c_prios, c_nodes))
-            n_sorted1 = c_nodes[order1]
-            seg_start1 = np.concatenate(
-                [[True], n_sorted1[1:] != n_sorted1[:-1]]
-            )
-            seg_id1 = np.cumsum(seg_start1) - 1
-            first_pos = np.flatnonzero(seg_start1)
-            for j in range(j_pdbs):
-                mj = pdb_match_c[j][order1].astype(np.int64)
-                cum = np.cumsum(mj)
-                base = (cum - mj)[first_pos]  # exclusive cum at segment start
-                rank = cum - base[seg_id1]  # inclusive count within segment
-                violating1[order1] |= (mj > 0) & (rank > pdb_list[j][2])
-
-        # greedy eviction order per node: non-violating first, lowest
-        # priority first, later placements first on ties
-        order2 = np.lexsort((-cand, c_prios, violating1, c_nodes))
-        n2 = c_nodes[order2]
-        seg_start2 = np.concatenate([[True], n2[1:] != n2[:-1]])
-        seg_id2 = np.cumsum(seg_start2) - 1
-        n_segs = int(seg_id2[-1]) + 1
-        seg_first = np.flatnonzero(seg_start2)
-        seg_node = n2[seg_first]
-
-        def seg_cumsum(vals):
-            """Within-segment inclusive cumulative sum along order2."""
-            cum = np.cumsum(vals, axis=0)
-            base = (cum - vals)[seg_first]
-            return cum - base[seg_id2]
-
-        req2 = placed_req[cand][order2]  # [C, R]
-        cum_req = seg_cumsum(req2)
-        free0 = (alloc - used)[seg_node[seg_id2]]  # [C, R] start free per row
-        res_ok = np.all(
-            free0 + cum_req >= pod_req[None, :] - 1e-6, axis=1
-        )
-        if reason == FAIL_GPU:
-            gpu_free0 = tz.ext.gpu_dev_total.sum(axis=1) - model["gpu_used_n"]
-            cum_gpu = seg_cumsum(model["gpu_use_log"][cand][order2])
-            res_ok &= (
-                gpu_free0[seg_node[seg_id2]] + cum_gpu >= gpu_need - 1e-6
-            )
-        elif reason == FAIL_STORAGE:
-            vg_free0 = (
-                tz.ext.vg_cap.sum(axis=1) - tz.ext.vg_req0.sum(axis=1)
-            ) - model["vg_used_n"]
-            cum_vg = seg_cumsum(model["vg_use_log"][cand][order2])
-            res_ok &= vg_free0[seg_node[seg_id2]] + cum_vg >= lvm_need - 1e-6
-        elif reason in (FAIL_PORTS, FAIL_INTERPOD, FAIL_SPREAD, FAIL_VOLUME, FAIL_ATTACH):
-            # every relevant victim on the node must go (a single eviction
-            # may leave another conflicting holder or a saturated class)
-            is_last = np.concatenate([seg_start2[1:], [True]])
-            res_ok &= is_last
-
-        # minimal qualifying prefix per segment
-        pos_in_seg = np.arange(len(order2)) - seg_first[seg_id2]
-        first_ok = np.full(n_segs, np.iinfo(np.int64).max)
-        ok_pos = np.flatnonzero(res_ok)
-        np.minimum.at(first_ok, seg_id2[ok_pos], pos_in_seg[ok_pos])
-        valid_seg = first_ok < np.iinfo(np.int64).max
-        if not valid_seg.any():
-            return None
-
-        # pickOneNode key on each segment's prefix: (PDB violations counted
-        # in eviction order, highest victim priority, summed priorities,
-        # victim count, node index)
-        prio2 = c_prios[order2].astype(np.float64)
-        cum_prio = seg_cumsum(prio2)
-        # segmented running max via monotone per-segment offsets: shift
-        # priorities to [0, range] and add seg_id*(range+1) — offsets stay
-        # far below 2^53, so the subtraction is exact
-        p_min = float(prio2.min())
-        span = float(prio2.max()) - p_min + 1.0
-        off = seg_id2.astype(np.float64) * span
-        cum_max = np.maximum.accumulate(prio2 - p_min + off) - off + p_min
-        if j_pdbs:
-            viol2 = np.zeros(len(order2), bool)
-            for j in range(j_pdbs):
-                mj = pdb_match_c[j][order2].astype(np.int64)
-                rank = seg_cumsum(mj)
-                viol2 |= (mj > 0) & (rank > pdb_list[j][2])
-            cum_viol = seg_cumsum(viol2.astype(np.int64))
+        key = (reason, cls.key)
+        table = model["tables"].get(key)
+        if table is None:
+            table = model["tables"][key] = self._victim_table(cls, reason, model)
+            best = table.cold_best()
         else:
-            cum_viol = np.zeros(len(order2), np.int64)
-        sel = seg_first + np.where(valid_seg, first_ok, 0)
-        keys = np.lexsort(
-            (
-                seg_node,
-                first_ok + 1,
-                cum_prio[sel],
-                cum_max[sel],
-                cum_viol[sel],
-                ~valid_seg,  # invalid segments last
-            )
-        )
-        best_seg = int(keys[0])
-        if not valid_seg[best_seg]:
+            dirty = model["dirty"]
+            nodes = set(dirty[table.seen :])
+            table.seen = len(dirty)
+            for n in nodes:
+                cand = table.candidates(model, n)
+                table.update(n, self._victim_segments(model, cls, reason, cand))
+            REGISTRY.counter("preempt.refreshes").inc(len(nodes))
+            best = table.best()
+        if best is None:
             return None
-        node = int(seg_node[best_seg])
-        a = int(seg_first[best_seg])
-        b = a + int(first_ok[best_seg]) + 1
-        victims = [int(cand[i]) for i in order2[a:b]]
+        node, victims = best
 
         # debit the model so later proposals in this wave see the eviction
         # AND the preemptor's own predicted landing on the freed node —
@@ -1012,30 +975,215 @@ class Simulator:
         # node wins the fewest-victims key) and the whole wave fails
         # verification. The prediction can be wrong (the batched verify
         # places wherever the real pipeline says); the verify corrects it.
-        model["evicted"][victims] = True
         model["prios"][victims] = np.inf
-        model["used"][node] -= placed_req[victims].sum(axis=0)
-        model["used"][node] += pod_req
+        model["used"][node] -= model["placed_req"][victims].sum(axis=0)
+        model["used"][node] += cls.req
         model["gpu_used_n"][node] -= model["gpu_use_log"][victims].sum()
-        model["gpu_used_n"][node] += gpu_need
+        model["gpu_used_n"][node] += cls.gpu_need
         model["vg_used_n"][node] -= model["vg_use_log"][victims].sum()
-        model["vg_used_n"][node] += lvm_need
+        model["vg_used_n"][node] += cls.lvm_need
+        model["dirty"].append(node)
         return victims
 
-    def _pod_req_vector(self, pod: dict):
-        """The pod's request row in the tensorizer's resource vocabulary."""
+    def _victim_table(self, cls: "_PodClass", reason: int, model: dict):
+        """The cold pass: `cls`'s victim search over the whole log."""
         import numpy as np
 
-        from .core.objects import pod_requests
-        from .core.tensorize import RES_PODS
+        REGISTRY.counter("preempt.tables").inc()
+        node_ok = np.asarray(self._tensorizer._static_mask[cls.gid], bool).copy()
+        if getattr(self._engine, "node_valid", None) is not None:
+            # fault-masked nodes (simtpu/faults/drain.py) are not landing
+            # sites: the engine's filter pipeline is guaranteed to reject
+            # them at verify, so proposing one only burns a wave
+            node_ok &= np.asarray(self._engine.node_valid, bool)
+        if cls.pin != -1:
+            # the pin restricts WITHIN the static mask (the serial loop
+            # checked static first): a pinned node the pod can never place
+            # on must not trigger a doomed evict/retry/restore round-trip
+            keep = cls.pin >= 0 and bool(node_ok[cls.pin])
+            node_ok[:] = False
+            if keep:
+                node_ok[cls.pin] = True
+        relevant = self._victim_relevance(cls.gid, reason, model)
+        cand = np.flatnonzero(
+            (model["prios"] < cls.prio) & relevant & node_ok[model["placed_nodes"]]
+        )
+        return _VictimTable(
+            cls, relevant, node_ok, len(model["dirty"]),
+            self._victim_segments(model, cls, reason, cand),
+        )
 
-        req = np.zeros(len(self._tensorizer.resources), np.float32)
-        req[RES_PODS] = 1.0
-        for rname, val in pod_requests(pod).items():
-            ridx = self._tensorizer.resources.get(rname)
-            if ridx >= 0:
-                req[ridx] = val
-        return req
+    def _victim_relevance(self, gid: int, reason: int, model: dict):
+        """Per log entry: can evicting it help a pod of group `gid` that
+        failed with `reason`? At group granularity where possible."""
+        import numpy as np
+
+        tz = self._tensorizer
+        placed_groups = model["placed_groups"]
+        groups = range(len(tz.groups))
+        if reason == FAIL_PORTS:
+            pod_ports = set(tz._port_rows[gid].keys())
+            rel_g = [bool(pod_ports & set(tz._port_rows[vg].keys())) for vg in groups]
+        elif reason == FAIL_INTERPOD:
+            anti_terms = {t for t, v in tz._a_anti[gid].items() if v}
+            rel_g = [any(tz._s_match[vg].get(t) for t in anti_terms) for vg in groups]
+        elif reason == FAIL_SPREAD:
+            spread_terms = {t for t, v in tz._spread_hard[gid].items() if v > 0}
+            rel_g = [any(tz._s_match[vg].get(t) for t in spread_terms) for vg in groups]
+        elif reason == FAIL_VOLUME:
+            # the victim must hold one of the conflicting volume identities
+            # via a rw/ro mount — attach-only usage (resolved PVC
+            # attachables) cannot cause a VolumeRestrictions conflict
+            keys = set(tz._vol_rw_rows[gid]) | set(tz._vol_ro_rows[gid])
+            rel_g = [
+                bool(keys & (set(tz._vol_rw_rows[vg]) | set(tz._vol_ro_rows[vg])))
+                for vg in groups
+            ]
+        elif reason == FAIL_ATTACH:
+            # evicting any holder of a same-class attachable frees a slot
+            classes = {
+                tz._vol_class[w] for w in tz._vol_att_rows[gid] if w in tz._vol_class
+            }
+            rel_g = [
+                bool(
+                    classes
+                    & {
+                        tz._vol_class[w]
+                        for w in set(tz._vol_att_rows[vg]) | set(tz._vol_rw_rows[vg])
+                        if w in tz._vol_class
+                    }
+                )
+                for vg in groups
+            ]
+        elif reason == FAIL_GPU:
+            return model["gpu_mem_log"] > 0
+        elif reason == FAIL_STORAGE:
+            return (model["vg_use_log"] > 0) | model["sd_any_log"]
+        else:  # FAIL_RESOURCES: any eviction frees resources
+            return np.ones(len(placed_groups), bool)
+        return np.array(rel_g, bool)[placed_groups]
+
+    def _pdbs_matching(self, model: dict, i: int) -> tuple:
+        """Indices of the wave's PDBs that cover log entry `i`, cached.
+
+        filterPodsWithPDBViolation semantics: a PDB with a nil or EMPTY
+        selector matches nothing here — unlike the general LabelSelector
+        rule — and unlabeled pods match no PDB (upstream short-circuits on
+        `len(pod.Labels) != 0`, default_preemption.go:745-746, even though
+        a DoesNotExist selector would otherwise match them; parity kept
+        deliberately)."""
+        cache = model["pdb_rows"]
+        got = cache.get(i)
+        if got is None:
+            from .core.match import match_label_selector
+            from .core.objects import labels_of
+
+            victim = self._scheduled[i]
+            labels = labels_of(victim)
+            got = cache[i] = tuple(
+                j
+                for j, (ns, sel, _) in enumerate(model["pdbs"])
+                if labels
+                and ns == namespace_of(victim)
+                and sel
+                and (sel.get("matchLabels") or sel.get("matchExpressions"))
+                and match_label_selector(sel, labels)
+            )
+        return got
+
+    def _victim_segments(self, model: dict, cls: "_PodClass", reason: int, cand):
+        """The victim search over candidate log entries `cand` (ascending),
+        one segment per node, or None without candidates: the PDB reprieve
+        split, the greedy eviction prefix, and the pickOneNode key of each
+        segment's minimal qualifying prefix. A segment reads its own
+        entries and its node's model rows only, and every running sum and
+        maximum stays inside the segment (`_Segments.scan`), so a segment's
+        answer is the same whether it is searched alone or with the whole
+        log."""
+        import numpy as np
+
+        if not len(cand):
+            return None
+        placed_nodes = model["placed_nodes"]
+        c_nodes = placed_nodes[cand]
+        c_prios = model["prios"][cand]
+        seg = _Segments(np.sort(c_nodes))  # the layout of every order below
+
+        # PDB reprieve split (filterPodsWithPDBViolation): walk each node's
+        # candidates in MoreImportantPod order (priority desc, index asc)
+        # decrementing budgets; a victim is VIOLATING once a matching PDB's
+        # budget goes negative. Vectorized as per-(pdb, node) running counts
+        # along the sorted order.
+        pdbs = model["pdbs"]
+        violating1 = np.zeros(len(cand), bool)
+        if pdbs:
+            match = np.zeros((len(pdbs), len(cand)), bool)
+            for ci, i in enumerate(cand.tolist()):
+                for j in self._pdbs_matching(model, i):
+                    match[j, ci] = True
+            order1 = np.lexsort((cand, -c_prios, c_nodes))
+            for j, (_, _, allowed) in enumerate(pdbs):
+                mj = match[j][order1].astype(np.int64)
+                violating1[order1] |= (mj > 0) & (seg.scan(np.add, mj) > allowed)
+
+        # greedy eviction order per node: non-violating first, lowest
+        # priority first, later placements first on ties
+        order2 = np.lexsort((-cand, c_prios, violating1, c_nodes))
+        rows = cand[order2]
+        tz = self._tensorizer
+        r = tz.alloc.shape[1]
+        # running sums in float64 of: requests | priority | GPU or VG use
+        vals = np.zeros((len(rows), r + 2))
+        vals[:, :r] = model["placed_req"][rows]
+        vals[:, r] = c_prios[order2]
+        at = seg.node[seg.id]  # each row's node
+        if reason == FAIL_GPU:
+            vals[:, r + 1] = model["gpu_use_log"][rows]
+        elif reason == FAIL_STORAGE:
+            vals[:, r + 1] = model["vg_use_log"][rows]
+        cum = seg.scan(np.add, vals)
+        free0 = tz.alloc[at] - model["used"][at]  # [C, R] start free per row
+        res_ok = np.all(free0 + cum[:, :r] >= cls.req[None, :] - 1e-6, axis=1)
+        if reason == FAIL_GPU:
+            gpu_free0 = model["gpu_cap"][at] - model["gpu_used_n"][at]
+            res_ok &= gpu_free0 + cum[:, r + 1] >= cls.gpu_need - 1e-6
+        elif reason == FAIL_STORAGE:
+            vg_free0 = model["vg_cap"][at] - model["vg_used_n"][at]
+            res_ok &= vg_free0 + cum[:, r + 1] >= cls.lvm_need - 1e-6
+        elif reason in (FAIL_PORTS, FAIL_INTERPOD, FAIL_SPREAD, FAIL_VOLUME, FAIL_ATTACH):
+            # every relevant victim on the node must go (a single eviction
+            # may leave another conflicting holder or a saturated class)
+            res_ok &= seg.last
+
+        # minimal qualifying prefix per segment: its length, 0 where none
+        ok = np.flatnonzero(res_ok)
+        ok_id = seg.id[ok]
+        first = np.ones(len(ok), bool)  # the first qualifying row of its segment
+        first[1:] = ok_id[1:] != ok_id[:-1]
+        count = np.zeros(len(seg.first), np.int64)
+        count[ok_id[first]] = seg.pos[ok[first]] + 1
+
+        # pickOneNode key on each segment's prefix: (PDB violations counted
+        # in eviction order, highest victim priority, summed priorities,
+        # victim count, node index)
+        sel = seg.first + np.maximum(count - 1, 0)
+        if pdbs:
+            viol2 = np.zeros(len(rows), bool)
+            for j, (_, _, allowed) in enumerate(pdbs):
+                mj = match[j][order2].astype(np.int64)
+                viol2 |= (mj > 0) & (seg.scan(np.add, mj) > allowed)
+            viol = seg.scan(np.add, viol2.astype(np.int64))[sel]
+        else:
+            viol = np.zeros(len(sel), np.int64)
+        return _SegmentSearch(
+            rows=rows,
+            node=seg.node,
+            first=seg.first,
+            count=count,
+            viol=viol,
+            top=seg.scan(np.maximum, vals[:, r])[sel],
+            total=cum[sel, r],
+        )
 
     def _result(self) -> SimulateResult:
         with span("plan.materialize", nodes=len(self._nodes)):

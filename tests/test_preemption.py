@@ -720,3 +720,26 @@ def test_preemption_basic_uniform_priority_keeps_incremental(
     assert "IGNORED" not in err and "pods can preempt" not in err
     assert doc["success"] is True and doc["nodes_added"] == 8
     assert doc["preempted"] == 0
+
+
+@pytest.mark.parametrize("tiers", [(1,), (1, 5)], ids=["32", "32-two-tiers"])
+def test_preemption_basic_victim_tables(tmp_path, monkeypatch, capsys, tiers):
+    """The high pods are one class: each wave searches the whole log once
+    (`preempt.tables`) and then only the node each proposal debited
+    (`preempt.refreshes`), with the reference still reading 0 everywhere."""
+    from simtpu.obs.metrics import REGISTRY
+
+    before = REGISTRY.snapshot()
+    problem, doc, _, plan = _apply_preempt_basic(
+        tmp_path, monkeypatch, capsys, _preempt_basic_cfg(32, tiers))
+    after = REGISTRY.snapshot()
+    delta = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("preempt.waves", "preempt.preemptors", "preempt.tables",
+                       "preempt.refreshes")}
+    assert delta["preempt.waves"] >= 1
+    assert delta["preempt.tables"] == delta["preempt.waves"]
+    assert delta["preempt.refreshes"] >= delta["preempt.preemptors"] - 1
+    assert doc["preempted"] == 3 * 32 and doc["unscheduled"] == 0
+    assert _reference_numbers(problem, doc, plan) == dict.fromkeys(
+        ("overcommit", "lost_pods", "unscheduled", "victim_priority",
+         "reprievable", "victims_wrong", "answer_mismatch"), 0)
