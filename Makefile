@@ -5,7 +5,7 @@
 
 PY ?= python
 
-.PHONY: all install lint test test-all test-perf bench bench-cold bench-faults bench-layout bench-durable bench-audit bench-solve bench-obs bench-explain bench-multihost bench-serve bench-timeline bench-scan bench-grow fuzz-smoke clean
+.PHONY: all install lint test test-all test-perf bench bench-cold bench-faults bench-layout bench-durable bench-audit bench-solve bench-obs bench-explain bench-multihost bench-serve bench-timeline bench-grow fuzz-smoke clean
 
 all: test
 
@@ -205,24 +205,7 @@ bench-timeline:
 	SIMTPU_BENCH_OBS=0 SIMTPU_BENCH_EXPLAIN=0 SIMTPU_BENCH_SERVE=0 \
 	$(PY) bench.py
 
-# round-16 scan/delta perf-lever smoke (mirrors bench-timeline): the
-# all-heavy storage+GPU+ports wavefront A/B (bit-identical, accepts > 0,
-# >= 1.5x the pod-at-a-time floor), the direct compact-delta evict/
-# restore churn (counter-pinned, bit-identical, beats the expand ->
-# apply -> recompress round trip), and a small timeline replay pinned
-# bit-identical across SIMTPU_DELTA_DIRECT=1/0 — scan_smoke_* land in
-# the JSON line (CI runs this alongside the fast tier)
-bench-scan:
-	SIMTPU_BENCH_SCAN_SMOKE=1 SIMTPU_BENCH_SCAN_SMOKE_ASSERT=1 \
-	SIMTPU_BENCH_NODES=500 SIMTPU_BENCH_PODS=2000 \
-	SIMTPU_BENCH_SCAN_PODS=200 SIMTPU_BENCH_BASELINE_PODS=50 \
-	SIMTPU_BENCH_SMALL=0 SIMTPU_BENCH_HARD=0 SIMTPU_BENCH_MATRIX=0 \
-	SIMTPU_BENCH_PLAN=0 SIMTPU_BENCH_BIG=0 SIMTPU_BENCH_FAULTS=0 \
-	SIMTPU_BENCH_LAYOUT=0 SIMTPU_BENCH_DURABLE=0 SIMTPU_BENCH_AUDIT=0 \
-	SIMTPU_BENCH_OBS=0 SIMTPU_BENCH_EXPLAIN=0 SIMTPU_BENCH_SERVE=0 \
-	SIMTPU_BENCH_TIMELINE=0 $(PY) bench.py
-
-# round-20 warm-engine serving smoke (mirrors bench-scan): append-only
+# round-20 warm-engine serving smoke (mirrors bench-timeline): append-only
 # vocabulary growth A/B — warm grow-engine waves vs re-tensorize+replay
 # (bit-identical, zero rebuilds, recompiles bounded by the pow2 buckets
 # touched) and the in-process warm-vs-cold serve fit QPS comparison
@@ -236,7 +219,7 @@ bench-grow:
 	SIMTPU_BENCH_PLAN=0 SIMTPU_BENCH_BIG=0 SIMTPU_BENCH_FAULTS=0 \
 	SIMTPU_BENCH_LAYOUT=0 SIMTPU_BENCH_DURABLE=0 SIMTPU_BENCH_AUDIT=0 \
 	SIMTPU_BENCH_OBS=0 SIMTPU_BENCH_EXPLAIN=0 SIMTPU_BENCH_SERVE=0 \
-	SIMTPU_BENCH_TIMELINE=0 SIMTPU_BENCH_SCAN_SMOKE=0 $(PY) bench.py
+	SIMTPU_BENCH_TIMELINE=0 $(PY) bench.py
 
 # differential fuzz over the fixed seed corpus at small shapes, across
 # the FULL engine-config matrix — 8 forced host devices arm the

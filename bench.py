@@ -1346,262 +1346,6 @@ def timeline_point() -> dict:
     return out
 
 
-def scan_smoke_point() -> dict:
-    """Round-16 perf-lever point (`make bench-scan` = the small-shape
-    asserting smoke, SIMTPU_BENCH_SCAN_SMOKE_ASSERT=1).  Three A/Bs:
-
-    (a) universal wavefront drafting: an ALL-heavy storage+GPU+ports mix
-        (every pod carries LVM, exclusive-device, GPU-share, or hostPort
-        demand — pods the pre-round-16 mask never drafted) through the
-        serial scan vs the wavefront dispatcher.  Asserts bit-identical
-        placements, `wavefront.draft_hard` engaged, accepts > 0, and the
-        wavefront rate >= 1.5x the pod-at-a-time floor.
-    (b) direct compact-delta preemption: engine-level evict/restore churn
-        on a compact carry under SIMTPU_DELTA_DIRECT=1 vs 0.  Asserts the
-        direct counter fires (zero expand/recompress), the round-trip
-        path reproduces the carry bit-identically, and direct throughput
-        beats the expand->apply->recompress round trip.
-    (c) a small timeline replay (departures/faults ride the same delta
-        arithmetic) is bit-identical between the two settings.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from simtpu import constants as C
-    from simtpu.core.objects import AppResource, ResourceTypes, set_label
-    from simtpu.core.tensorize import Tensorizer
-    from simtpu.engine.scan import (
-        WAVE_KEYS,
-        build_pod_arrays,
-        default_wave_call,
-        flags_from,
-        run_scan_chunked,
-        statics_from,
-    )
-    from simtpu.engine.state import CompactState, build_state
-    from simtpu.obs.metrics import REGISTRY
-    from simtpu.obs.metrics import family as metrics_family
-    from simtpu.synth import make_deployment, synth_apps, synth_cluster
-    from simtpu.workloads.expand import (
-        get_valid_pods_exclude_daemonset,
-        seed_name_hashes,
-    )
-
-    do_assert = os.environ.get("SIMTPU_BENCH_SCAN_SMOKE_ASSERT", "") == "1"
-    out = {}
-
-    # ---- (a) heavy wavefront drafting --------------------------------
-    note("scan smoke: all-heavy storage+GPU+ports wavefront A/B")
-    cluster = synth_cluster(
-        48, seed=17, zones=3, taint_frac=0.0, gpu_frac=0.6, storage_frac=0.6
-    )
-    res = ResourceTypes()
-    res.deployments = [
-        make_deployment("lvmy", 128, 400, 200, lvm_gib=4),
-        make_deployment("gpuey", 128, 400, 200, gpu_mem_mib=512),
-        make_deployment("devy", 64, 300, 200, device_gib=10),
-        make_deployment("porty", 40, 100, 128, host_port=8080),
-    ]
-    seed_name_hashes(0)
-    pods = []
-    for app in [AppResource(name="heavy", resource=res)]:
-        for pod in get_valid_pods_exclude_daemonset(app.resource):
-            set_label(pod, C.LABEL_APP_NAME, app.name)
-            pods.append(pod)
-    tz = Tensorizer(cluster.nodes, storage_classes=cluster.storage_classes)
-    batch = tz.add_pods(pods)
-    tensors = tz.freeze()
-    statics = statics_from(tensors)
-    r = tensors.alloc.shape[1]
-    _req, pod_arrays = build_pod_arrays(batch, r)
-    state0 = build_state(
-        tensors, np.zeros(0, np.int32), np.zeros(0, np.int32),
-        np.zeros((0, r), np.float32), None,
-    )
-    flags = flags_from(tensors, batch.ext)
-    groups = np.asarray(batch.group)
-
-    def timed_scan(speculate):
-        def go(st):
-            _, outs = run_scan_chunked(
-                statics, st, pod_arrays, flags, tensors, groups,
-                wave_call=default_wave_call if speculate else None,
-            )
-            return outs[0]
-
-        go(jax.tree.map(jnp.copy, state0))  # compile + warm
-        best = None
-        nodes = None
-        for _ in range(2):  # best-of-2: one noisy wall must not flake CI
-            fresh = jax.tree.map(jnp.copy, state0)
-            jax.block_until_ready(fresh)
-            t0 = time.perf_counter()
-            nodes = go(fresh)
-            best = min(best, time.perf_counter() - t0) if best else (
-                time.perf_counter() - t0
-            )
-        return best, nodes
-
-    before = metrics_family("wavefront", WAVE_KEYS)
-    serial_wall, serial_nodes = timed_scan(False)
-    wave_wall, wave_nodes = timed_scan(True)
-    after = metrics_family("wavefront", WAVE_KEYS)
-    n_pods = len(groups)
-    floor_rate = n_pods / serial_wall
-    wave_rate = n_pods / wave_wall
-    drafted = after["pods"] - before["pods"]
-    accepted = after["accepted"] - before["accepted"]
-    hard = after["draft_hard"] - before["draft_hard"]
-    identical = bool(np.array_equal(serial_nodes, wave_nodes))
-    note(
-        f"scan smoke: heavy mix floor={floor_rate:.0f} pods/s "
-        f"wavefront={wave_rate:.0f} pods/s "
-        f"({wave_rate / floor_rate:.2f}x), drafted={drafted} "
-        f"hard={hard} accepted={accepted} identical={identical}"
-    )
-    out["scan_smoke_heavy_floor_pods_per_s"] = round(floor_rate, 1)
-    out["scan_smoke_heavy_wavefront_pods_per_s"] = round(wave_rate, 1)
-    out["scan_smoke_heavy_speedup"] = round(wave_rate / floor_rate, 2)
-    out["scan_smoke_heavy_accepted"] = accepted
-    out["scan_smoke_heavy_draft_hard"] = hard
-    if do_assert:
-        assert identical, "heavy wavefront diverged from the serial scan"
-        assert hard > 0, "heavy mix never rode the hard verifier"
-        assert accepted > 0, "wavefront accept rate is 0 on the heavy mix"
-        assert wave_rate >= 1.5 * floor_rate, (
-            f"wavefront {wave_rate:.0f} pods/s under 1.5x the "
-            f"{floor_rate:.0f} pods/s pod-at-a-time floor"
-        )
-
-    # ---- (b) direct compact-delta preemption churn -------------------
-    note("scan smoke: direct compact-delta evict/restore A/B")
-    from simtpu.faults import place_cluster
-
-    pcluster = synth_cluster(
-        1000, seed=5, zones=8, taint_frac=0.1, gpu_frac=0.2, storage_frac=0.3
-    )
-    papps = synth_apps(
-        4000, seed=6, zones=8, pods_per_deployment=50,
-        selector_frac=0.2, anti_affinity_frac=0.3, spread_frac=0.4,
-        gpu_frac=0.1, storage_frac=0.2,
-    )
-    pc = place_cluster(pcluster, papps)
-    eng = pc.engine
-    idx = list(range(0, len(eng.placed_node), 7))
-    base_carry = jax.tree_util.tree_map(
-        lambda a: np.asarray(a).copy(), eng.last_state
-    )
-
-    def churn(cycles):
-        t0 = time.perf_counter()
-        for _ in range(cycles):
-            saved = eng.remove_placements(idx)
-            eng.restore_placements(saved)
-        jax.block_until_ready(eng.last_state.free)
-        return time.perf_counter() - t0
-
-    walls = {}
-    counters = {}
-    prev = os.environ.get("SIMTPU_DELTA_DIRECT")
-    try:
-        for mode in ("1", "0"):
-            os.environ["SIMTPU_DELTA_DIRECT"] = mode
-            churn(1)  # compile + warm this mode's dispatches
-            s0 = REGISTRY.snapshot()
-            walls[mode] = churn(4)
-            s1 = REGISTRY.snapshot()
-            counters[mode] = {
-                k: s1.get(k, 0) - s0.get(k, 0)
-                for k in ("state.delta_direct", "state.expand", "state.compress")
-            }
-    finally:
-        if prev is None:
-            os.environ.pop("SIMTPU_DELTA_DIRECT", None)
-        else:
-            os.environ["SIMTPU_DELTA_DIRECT"] = prev
-    deltas = 8  # 4 cycles x (evict + restore)
-    note(
-        f"scan smoke: {deltas} deltas of {len(idx)} entries — "
-        f"direct {walls['1']:.3f}s {counters['1']}, "
-        f"round-trip {walls['0']:.3f}s {counters['0']}"
-    )
-    out["scan_smoke_delta_direct_s"] = round(walls["1"], 3)
-    out["scan_smoke_delta_roundtrip_s"] = round(walls["0"], 3)
-    out["scan_smoke_delta_speedup"] = round(walls["0"] / walls["1"], 2)
-    if do_assert:
-        assert isinstance(eng.last_state, CompactState), "carry not compact"
-        assert counters["1"]["state.delta_direct"] == deltas, counters
-        assert counters["1"]["state.expand"] == 0, counters
-        assert counters["1"]["state.compress"] == 0, counters
-        assert counters["0"]["state.delta_direct"] == 0, counters
-        assert counters["0"]["state.expand"] == deltas, counters
-        for name in base_carry._fields:
-            assert np.array_equal(
-                np.asarray(getattr(eng.last_state, name)),
-                getattr(base_carry, name),
-            ), f"carry plane {name} drifted across the churn A/B"
-        # the direct scatter must actually beat the expand->apply->
-        # recompress round trip (measured ~13x at this shape; 2x keeps a
-        # wide flake margin on loaded CI hosts)
-        assert walls["1"] * 2 < walls["0"], (
-            f"direct {walls['1']:.3f}s not faster than round trip "
-            f"{walls['0']:.3f}s"
-        )
-
-    # ---- (c) timeline replay bit-identity across the A/B -------------
-    note("scan smoke: timeline replay delta-direct A/B")
-    from simtpu.engine.state import diff_state_planes
-    from simtpu.synth import make_trace
-    from simtpu.timeline import ReplayOptions, replay_trace, trace_from_doc
-
-    doc = make_trace(
-        16, 360, seed=21, days=0.2, mean_gang=8,
-        cron_jobs=2, elastic_frac=0.1, node_event_frac=0.05,
-        duration_mean_s=3600.0,
-    )
-    runs = {}
-    prev = os.environ.get("SIMTPU_DELTA_DIRECT")
-    try:
-        for mode in ("1", "0"):
-            os.environ["SIMTPU_DELTA_DIRECT"] = mode
-            s0 = REGISTRY.snapshot()
-            runs[mode] = replay_trace(
-                trace_from_doc(doc, source="<bench-scan>"),
-                ReplayOptions(speculate=True),
-            )
-            s1 = REGISTRY.snapshot()
-            runs[mode + "_direct"] = s1.get("state.delta_direct", 0) - s0.get(
-                "state.delta_direct", 0
-            )
-    finally:
-        if prev is None:
-            os.environ.pop("SIMTPU_DELTA_DIRECT", None)
-        else:
-            os.environ["SIMTPU_DELTA_DIRECT"] = prev
-    same_nodes = bool(np.array_equal(runs["1"].nodes, runs["0"].nodes))
-    plane_diffs = diff_state_planes(
-        runs["1"].end_state(), runs["0"].end_state()
-    )
-    note(
-        f"scan smoke: timeline identical={same_nodes} "
-        f"(direct deltas {runs['1_direct']} vs {runs['0_direct']})"
-    )
-    out["scan_smoke_timeline_identical"] = same_nodes and not plane_diffs
-    out["scan_smoke_timeline_direct_deltas"] = runs["1_direct"]
-    if do_assert:
-        assert same_nodes, "timeline landing vectors differ across the A/B"
-        assert not plane_diffs, f"timeline end-state differs: {plane_diffs}"
-        assert runs["1"].event_log == runs["0"].event_log, (
-            "timeline event logs differ across the A/B"
-        )
-        assert runs["1_direct"] > 0, (
-            "timeline departures never rode the direct delta path"
-        )
-        assert runs["0_direct"] == 0, "A/B off-leg still took the direct path"
-        note("scan smoke asserts passed")
-    return out
-
-
 def grow_point() -> dict:
     """Warm-engine serving point (`make bench-grow` = the asserting
     smoke, SIMTPU_BENCH_GROW_ASSERT=1).  Two measurements:
@@ -2550,17 +2294,6 @@ def main() -> int:
         except Exception as exc:  # noqa: BLE001 - report, keep the line
             note(f"timeline point failed: {type(exc).__name__}: {exc}")
             record["timeline_error"] = f"{type(exc).__name__}: {exc}"
-    # round-16 scan/delta perf levers (ISSUE 16): on by default at
-    # north-star runs, SIMTPU_BENCH_SCAN_SMOKE=1 forces it at any
-    # configuration (`make bench-scan` = the small-shape asserting
-    # smoke), =0 skips
-    scan_env = os.environ.get("SIMTPU_BENCH_SCAN_SMOKE", "")
-    if scan_env != "0" and (north_star or scan_env == "1"):
-        try:
-            record.update(scan_smoke_point())
-        except Exception as exc:  # noqa: BLE001 - report, keep the line
-            note(f"scan smoke point failed: {type(exc).__name__}: {exc}")
-            record["scan_smoke_error"] = f"{type(exc).__name__}: {exc}"
     # round-20 warm-engine serving (append-only vocabulary growth): on by
     # default at north-star runs, SIMTPU_BENCH_GROW=1 forces it at any
     # configuration (`make bench-grow` = the asserting smoke), =0 skips
@@ -2589,8 +2322,7 @@ def main() -> int:
         for key in (
             "plan_error", "big_point_error", "fault_error", "layout_error",
             "durable_error", "audit_error", "obs_error", "explain_error",
-            "serve_error", "timeline_error", "scan_smoke_error",
-            "grow_error",
+            "serve_error", "timeline_error", "grow_error",
         )
     ) else 0
 

@@ -62,7 +62,6 @@ from .state import (
     compact_enabled,
     compact_spec,
     compress_state,
-    delta_direct_enabled,
     expand_state,
     extend_state,
     extend_state_nodes,
@@ -173,29 +172,6 @@ def wave_enabled() -> bool:
     import os
 
     return os.environ.get("SIMTPU_WAVEFRONT", "1") != "0"
-
-
-def wave_heavy_enabled() -> bool:
-    """SIMTPU_WAVE_HEAVY=0 restricts wavefront drafting back to LEAN pods
-    (no storage/GPU demand, no ports/volume groups).  1/unset drafts the
-    heavy families too, through the hard verifier's per-step stage
-    recomputes — placements are bit-identical either way; the switch
-    exists for A/B measurement."""
-    import os
-
-    return os.environ.get("SIMTPU_WAVE_HEAVY", "1") != "0"
-
-
-def fused_cascade_enabled() -> bool:
-    """SIMTPU_FUSED_CASCADE=0 compiles the per-step filter/score cascade
-    with one lax.cond per skippable stage (the pre-round-16 form); 1/unset
-    merges adjacent same-shape conds into single wider branches so each
-    serial step issues fewer kernels.  Placements are bit-identical either
-    way (every skip constant equals the skipped kernel's degenerate
-    output); the switch exists for A/B measurement."""
-    import os
-
-    return os.environ.get("SIMTPU_FUSED_CASCADE", "1") != "0"
 
 
 REASON_TEXT = {
@@ -511,12 +487,6 @@ class StepFlags(NamedTuple):
     # (neither ≤ DOM_SMALL domains nor unique-per-node); False removes the
     # [Tc, D] scatter/gather pair from the bulk round entirely
     dom_fallback: bool = True
-    # merge adjacent same-shape lax.cond stages of the filter/score cascade
-    # into single wider branches (fewer dispatches per serial step); the
-    # merged form is bit-identical — every skip constant equals the skipped
-    # kernel's degenerate output, so evaluating a dormant term inside a
-    # taken branch reproduces the constant exactly
-    fused: bool = True
 
 
 def flags_from(tensors: ClusterTensors, batch_ext: dict) -> StepFlags:
@@ -553,7 +523,6 @@ def flags_from(tensors: ClusterTensors, batch_ext: dict) -> StepFlags:
         node_pref=bool(tensors.node_pref_score.any()),
         taint_pref=bool(tensors.taint_intolerable.any()),
         static_score=bool(tensors.static_score.any() or tensors.avoid_pen.any()),
-        fused=fused_cascade_enabled(),
     )
 
 
@@ -589,6 +558,23 @@ class StepEval(NamedTuple):
         for field, code in reversed(FILTER_CASCADE[:-1]):
             fail = jnp.where(jnp.any(getattr(self, field)), fail, code)
         return fail
+
+
+def _merged_cond(stages):
+    """One lax.cond for several per-pod skippable cascade stages, given as
+    (live predicate, live fn, skip-constant fn): taken when any stage is
+    live, it evaluates every live fn.  Exact only because a dormant
+    stage's live fn returns its skip constant bit for bit
+    (tests/test_cascade.py pins each)."""
+    any_live = stages[0][0]
+    for pred, _, _ in stages[1:]:
+        any_live = any_live | pred
+    return jax.lax.cond(
+        any_live,
+        lambda _: tuple(live() for _, live, _ in stages),
+        lambda _: tuple(skip() for _, _, skip in stages),
+        None,
+    )
 
 
 def score_pod(
@@ -639,8 +625,8 @@ def score_pod(
         score += w_[5] * taint_toleration_score(statics.taint_intol[g], m_all)
     n = statics.alloc.shape[0]
     # the three count-plane terms below are each individually skippable per
-    # pod (lax.cond) — collected as (weight index, live predicate, live fn,
-    # skip-constant fn) so the fused cascade can merge them into ONE cond
+    # pod — collected as (weight index, live predicate, live fn,
+    # skip-constant fn) and merged into ONE lax.cond
     soft_terms = []
     if (f.interpod_pref or f.interpod_req) and t_cap:
         # per-pod skip: a pod whose group carries no interpod terms gets
@@ -696,30 +682,12 @@ def score_pod(
             ),
             lambda: jnp.full(n, MAX_NODE_SCORE, score.dtype),
         ))
-    if f.fused and len(soft_terms) > 1:
-        # one cond for every count-plane term: a dormant term evaluated in
-        # the live branch reproduces its skip constant exactly (see the
-        # per-term notes above), so the merge is bit-identical while
-        # dispatching one branch pair instead of three
-        any_live = soft_terms[0][1]
-        for _, pred, _, _ in soft_terms[1:]:
-            any_live = any_live | pred
-        vals = jax.lax.cond(
-            any_live,
-            lambda _: tuple(fn() for _, _, fn, _ in soft_terms),
-            lambda _: tuple(fn() for _, _, _, fn in soft_terms),
-            None,
-        )
+    if soft_terms:
+        # one cond for every count-plane term (see the per-term notes above
+        # for each skip constant): one branch pair instead of three
+        vals = _merged_cond([(p, live, skip) for _, p, live, skip in soft_terms])
         for (wi, _, _, _), val in zip(soft_terms, vals):
             score += w_[wi] * val
-    else:
-        for wi, pred, live, skip in soft_terms:
-            score += w_[wi] * jax.lax.cond(
-                pred,
-                lambda _, fn=live: fn(),
-                lambda _, fn=skip: fn(),
-                None,
-            )
     # ImageLocality + NodePreferAvoidPods (static per group)
     if f.static_score:
         score += w_[9] * statics.static_score[g] + w_[11] * statics.avoid_pen[g]
@@ -803,68 +771,41 @@ def filter_and_score(
     if f.storage:
         needs_storage = jnp.any(lvm_size > 0) | jnp.any(dev_size > 0)
 
-        if f.fused:
-            # fused form: plan + the raw Open-Local score share ONE branch
-            # pair.  The skip branch's raw 0 min-max-normalizes to exactly
-            # 0 — the split form's separate score-skip constant — so the
-            # later storage term needs no second cond
-            def _storage_plan(_):
-                lvm_ok, lvm_alloc = lvm_plan(
-                    state.vg_free, statics.vg_name_id, lvm_size, lvm_vg
-                )
-                dev_ok, dev_take, dev_tight = device_plan(
-                    state.sdev_free,
-                    statics.sdev_cap,
-                    statics.sdev_media,
-                    dev_size,
-                    dev_media,
-                )
-                raw = open_local_score(
-                    lvm_alloc,
-                    statics.vg_cap,
-                    dev_tight,
-                    jnp.sum(lvm_size > 0),
-                    jnp.sum(dev_size > 0),
-                )
-                return statics.has_storage & lvm_ok & dev_ok, lvm_alloc, dev_take, raw
-
-            def _storage_skip(_):
-                return (
-                    jnp.ones(n, bool),
-                    jnp.zeros_like(statics.vg_cap),
-                    jnp.zeros(statics.sdev_cap.shape, bool),
-                    jnp.zeros(n, statics.vg_cap.dtype),
-                )
-
-            storage_ok, lvm_alloc, dev_take, storage_raw = jax.lax.cond(
-                needs_storage, _storage_plan, _storage_skip, None
+        # plan + the raw Open-Local score share ONE branch pair.  Zero
+        # claims score all-zero, and the skip branch's raw 0
+        # min-max-normalizes to exactly 0, so the later storage term
+        # needs no cond of its own
+        def _storage_plan(_):
+            lvm_ok, lvm_alloc = lvm_plan(
+                state.vg_free, statics.vg_name_id, lvm_size, lvm_vg
             )
-        else:
-
-            def _storage_plan(_):
-                lvm_ok, lvm_alloc = lvm_plan(
-                    state.vg_free, statics.vg_name_id, lvm_size, lvm_vg
-                )
-                dev_ok, dev_take, dev_tight = device_plan(
-                    state.sdev_free,
-                    statics.sdev_cap,
-                    statics.sdev_media,
-                    dev_size,
-                    dev_media,
-                )
-                return statics.has_storage & lvm_ok & dev_ok, lvm_alloc, dev_take, dev_tight
-
-            def _storage_skip(_):
-                return (
-                    jnp.ones(n, bool),
-                    jnp.zeros_like(statics.vg_cap),
-                    jnp.zeros(statics.sdev_cap.shape, bool),
-                    jnp.zeros(n, statics.vg_cap.dtype),
-                )
-
-            storage_ok, lvm_alloc, dev_take, dev_tight = jax.lax.cond(
-                needs_storage, _storage_plan, _storage_skip, None
+            dev_ok, dev_take, dev_tight = device_plan(
+                state.sdev_free,
+                statics.sdev_cap,
+                statics.sdev_media,
+                dev_size,
+                dev_media,
             )
+            raw = open_local_score(
+                lvm_alloc,
+                statics.vg_cap,
+                dev_tight,
+                jnp.sum(lvm_size > 0),
+                jnp.sum(dev_size > 0),
+            )
+            return statics.has_storage & lvm_ok & dev_ok, lvm_alloc, dev_take, raw
+
+        def _storage_skip(_):
+            return (
+                jnp.ones(n, bool),
+                jnp.zeros_like(statics.vg_cap),
+                jnp.zeros(statics.sdev_cap.shape, bool),
+                jnp.zeros(n, statics.vg_cap.dtype),
+            )
+
+        storage_ok, lvm_alloc, dev_take, storage_raw = jax.lax.cond(
+            needs_storage, _storage_plan, _storage_skip, None
+        )
         m_storage = m_bind & storage_ok
     else:
         lvm_alloc = jnp.zeros_like(statics.vg_cap)
@@ -931,35 +872,17 @@ def filter_and_score(
             | jnp.any(statics.s_match[g] & tvalid & (ip_eff >= 0))
         )
 
-    if f.fused and sh_active and ir_active:
-        # fused form: one branch pair for both [Tc, N]-streaming filters.
-        # A dormant filter evaluated in the live branch is all-True (zero
-        # maxSkew / no touching terms), matching its skip constant exactly
-        spread_m, ip_m = jax.lax.cond(
-            has_spread | touches_ip,
-            lambda _: (_spread_filter(), _ip_filter()),
-            lambda _: (jnp.ones(n, bool), jnp.ones(n, bool)),
-            None,
-        )
-        m_spread = m_gpu & spread_m
-        m_all = m_spread & ip_m
-    else:
-        m_spread = m_gpu
-        if sh_active:
-            m_spread = m_gpu & jax.lax.cond(
-                has_spread,
-                lambda _: _spread_filter(),
-                lambda _: jnp.ones(n, bool),
-                None,
-            )
-        m_all = m_spread
-        if ir_active:
-            m_all = m_spread & jax.lax.cond(
-                touches_ip,
-                lambda _: _ip_filter(),
-                lambda _: jnp.ones(n, bool),
-                None,
-            )
+    # one branch pair for both [Tc, N]-streaming filters: a dormant filter
+    # evaluated in the live branch is all-True (zero maxSkew / no touching
+    # terms), matching its skip constant exactly
+    hard_filters = []
+    if sh_active:
+        hard_filters.append((has_spread, _spread_filter, lambda: jnp.ones(n, bool)))
+    if ir_active:
+        hard_filters.append((touches_ip, _ip_filter, lambda: jnp.ones(n, bool)))
+    hard_m = _merged_cond(hard_filters) if hard_filters else ()
+    m_spread = m_gpu & hard_m[0] if sh_active else m_gpu
+    m_all = m_spread & hard_m[-1] if ir_active else m_spread
     feasible = jnp.any(m_all)
 
     # the Open-Local term is computed outside score_pod so the storage-free
@@ -968,34 +891,10 @@ def filter_and_score(
     score = score_pod(statics, state, g, req, m_all, flags)
     storage_term = 0.0
     if f.storage:
-        if f.fused:
-            # the fused storage cond already produced the raw score (0 for
-            # storage-free pods, which min-max-normalizes to exactly 0 —
-            # the split form's skip constant); only the cheap [N] normalize
-            # remains outside the branch
-            storage_term = statics.score_w[10] * minmax_normalize(
-                storage_raw, m_all
-            )
-        else:
-            # zero claims → open_local_score is all-zero → the normalized
-            # term is exactly 0 everywhere; skip the [N, V] streams for
-            # such pods
-            def _storage_term(_):
-                storage_raw = open_local_score(
-                    lvm_alloc,
-                    statics.vg_cap,
-                    dev_tight,
-                    jnp.sum(lvm_size > 0),
-                    jnp.sum(dev_size > 0),
-                )
-                return statics.score_w[10] * minmax_normalize(storage_raw, m_all)
-
-            storage_term = jax.lax.cond(
-                needs_storage,
-                _storage_term,
-                lambda _: jnp.zeros(n, statics.vg_cap.dtype),
-                None,
-            )
+        # the storage cond already produced the raw score (0 for
+        # storage-free pods, which min-max-normalizes to exactly 0); only
+        # the cheap [N] normalize remains outside the branch
+        storage_term = statics.score_w[10] * minmax_normalize(storage_raw, m_all)
 
     return StepEval(
         m_static=m_static,
@@ -1281,8 +1180,8 @@ def plan_scan_chunks(
     padded term-row list its count planes carry (None = full plane), and
     waves the chunk's wavefront sub-plan — absolute (a, b) ranges dispatched
     through the speculative wavefront executable instead of the general
-    scan (empty without `wave_ok`, the per-pod eligibility mask from
-    `wave_pod_mask`).
+    scan (empty without `wave_ok`, the per-pod eligibility coding from
+    `wave_eligibility`).
 
     Single source of truth for the chunk contexts — `run_scan_chunked`
     executes this plan, and the AOT precompiler (engine/precompile.py)
@@ -1566,9 +1465,10 @@ def run_scan_chunked(
 # exploits that, speculative-decoding style (docs/speculation.md):
 #
 # 1. A host-side planner partitions each chunk's pod sequence into
-#    wavefronts: maximal same-group runs of LEAN pods (unpinned, unforced,
-#    no storage/GPU demand, a group requesting no host ports or volumes —
-#    `wave_pod_mask`).  Everything else stays on the general serial scan.
+#    wavefronts: maximal same-group runs of unpinned, unforced pods with
+#    the same heavy stage bits (storage/GPU demand, a group requesting host
+#    ports or volumes — `wave_eligibility`).  Everything else stays on the
+#    general serial scan.
 # 2. One jitted call per wavefront (`_run_wavefront`) places the whole run:
 #    the speculative step evaluates the run's spec ONCE against the
 #    wavefront-start state (the batched placement every pod would get if
@@ -1609,50 +1509,6 @@ WAVE_HEAVY_STORAGE = 4  # pod demands Open-Local LVM / device storage
 WAVE_HEAVY_GPU = 8  # pod demands GPU shares
 
 
-def wave_group_mask(tensors) -> np.ndarray:
-    """[G] bool — groups whose pods can ride a wavefront: no host-port and
-    no volume requests, so two run members can only interact through free
-    resources and the group's own topology terms (both carried exactly by
-    the verifier).  Memoized on the tensors object."""
-    cached = getattr(tensors, "_wave_group_cache", None)
-    if cached is not None:
-        return cached
-    g_n = len(tensors.groups)
-    ok = np.ones(g_n, bool)
-    if tensors.n_ports:
-        ok &= ~tensors.ports.any(axis=1)
-    if tensors.n_vols:
-        ok &= ~(
-            tensors.vol_rw.any(axis=1)
-            | tensors.vol_ro.any(axis=1)
-            | tensors.vol_att.any(axis=1)
-        )
-    object.__setattr__(tensors, "_wave_group_cache", ok)
-    return ok
-
-
-def wave_pod_mask(pods, groups: np.ndarray, tensors) -> np.ndarray:
-    """[P] bool — pods eligible for wavefront placement.  With heavy
-    drafting on (the default) only pinned/forced pods are excluded: the
-    hard verifier recomputes the storage/GPU/ports/volume stages per step,
-    so those families draft too.  With SIMTPU_WAVE_HEAVY=0 the pre-round-16
-    LEAN restriction applies: no storage/GPU demand, unpinned, unforced,
-    and of a port/volume-free group.  Pure host-side numpy over the pod
-    tuple (`build_pod_arrays` layout)."""
-    ok = (np.asarray(pods[2]) == -1) & ~np.asarray(pods[3])
-    if wave_heavy_enabled():
-        return ok
-    lvm = np.asarray(pods[4])
-    if lvm.size:
-        ok &= lvm.max(axis=1) <= 0
-    dev = np.asarray(pods[6])
-    if dev.size:
-        ok &= dev.max(axis=1) <= 0
-    ok &= np.asarray(pods[8]) <= 0
-    ok &= wave_group_mask(tensors)[groups]
-    return ok
-
-
 def wave_group_heavy(tensors) -> np.ndarray:
     """[G] int16 — per-group heavy stage bits (WAVE_HEAVY_PORTS / _VOLS):
     which group-level constraint families the hard verifier must recompute
@@ -1678,25 +1534,24 @@ def wave_group_heavy(tensors) -> np.ndarray:
 
 
 def wave_eligibility(pods, groups: np.ndarray, tensors) -> np.ndarray:
-    """[P] int16 — -1 for wavefront-ineligible pods, else the heavy stage
-    bits the verifier needs for that pod (0 = pure LEAN).  The planner
-    breaks runs on value changes, so a run is homogeneous in both group and
-    heavy bits."""
-    ok = wave_pod_mask(pods, groups, tensors)
-    bits = np.zeros(len(ok), np.int16)
-    if wave_heavy_enabled():
-        bits = wave_group_heavy(tensors)[groups].astype(np.int16)
-        stor = np.zeros(len(ok), bool)
-        lvm = np.asarray(pods[4])
-        if lvm.size:
-            stor |= lvm.max(axis=1) > 0
-        dev = np.asarray(pods[6])
-        if dev.size:
-            stor |= dev.max(axis=1) > 0
-        bits = bits | np.where(stor, WAVE_HEAVY_STORAGE, 0).astype(np.int16)
-        bits = bits | np.where(
-            np.asarray(pods[8]) > 0, WAVE_HEAVY_GPU, 0
-        ).astype(np.int16)
+    """[P] int16 — -1 for wavefront-ineligible pods (pinned or forced),
+    else the heavy stage bits the hard verifier recomputes per step for
+    that pod (0 = pure LEAN).  The planner breaks runs on value changes, so
+    a run is homogeneous in both group and heavy bits.  Pure host-side
+    numpy over the pod tuple (`build_pod_arrays` layout)."""
+    ok = (np.asarray(pods[2]) == -1) & ~np.asarray(pods[3])
+    bits = wave_group_heavy(tensors)[groups].astype(np.int16)
+    stor = np.zeros(len(ok), bool)
+    lvm = np.asarray(pods[4])
+    if lvm.size:
+        stor |= lvm.max(axis=1) > 0
+    dev = np.asarray(pods[6])
+    if dev.size:
+        stor |= dev.max(axis=1) > 0
+    bits = bits | np.where(stor, WAVE_HEAVY_STORAGE, 0).astype(np.int16)
+    bits = bits | np.where(
+        np.asarray(pods[8]) > 0, WAVE_HEAVY_GPU, 0
+    ).astype(np.int16)
     return np.where(ok, bits, -1).astype(np.int16)
 
 
@@ -1745,16 +1600,12 @@ def _plan_waves(
     """Maximal same-group, same-eligibility runs of wavefront-eligible pods
     within [c0, c1), length >= _WAVE_MIN, as absolute
     (a, b, hard, pref, heavy) entries.  `wave_ok` is wave_eligibility's
-    int16 coding (-1 ineligible, else heavy bits); a bool mask (True/False
-    → 1/0 under comparison) keeps the pre-round-16 LEAN behaviour.  Any
-    heavy bit forces the hard verifier — the per-step stage recomputes live
-    there."""
+    int16 coding (-1 ineligible, else heavy bits).  Any heavy bit forces
+    the hard verifier — the per-step stage recomputes live there."""
     g = groups[c0:c1]
     if g.shape[0] == 0:
         return []
     ok = np.asarray(wave_ok[c0:c1])
-    if ok.dtype == bool:
-        ok = np.where(ok, 0, -1).astype(np.int16)
     brk = np.flatnonzero((g[1:] != g[:-1]) | (ok[1:] != ok[:-1])) + 1
     starts = np.concatenate([[0], brk])
     ends = np.concatenate([brk, [len(g)]])
@@ -2142,8 +1993,8 @@ def _wave_verify_hard(statics, state, xs, f, env):
         if heavy_storage:
             needs_storage = jnp.any(lvm_size > 0) | jnp.any(dev_size > 0)
 
-            # fused plan+raw branch pair — bit-identical to the general
-            # step's split conds (the skip raw 0 normalizes to exactly 0)
+            # plan+raw branch pair — the general step's own storage cond
+            # (the skip raw 0 normalizes to exactly 0)
             def _storage_plan(_):
                 lvm_ok, lvm_alloc = lvm_plan(
                     vg_free, statics.vg_name_id, lvm_size, lvm_vg
@@ -3340,7 +3191,7 @@ class Engine:
         )
         statics = statics_from(tensors, self.sched_config)
         state = self.last_state
-        if isinstance(state, CompactState) and delta_direct_enabled():
+        if isinstance(state, CompactState):
             # direct compact-delta apply: scatter the packed deltas straight
             # into the compact carry (per-domain histogram adds for kind-1
             # term rows, dense row updates for kind-0/2) — no
@@ -3363,12 +3214,9 @@ class Engine:
             self._state_dirty = False
             REGISTRY.counter("state.delta_direct").inc()
             return
-        if isinstance(state, CompactState):
-            state = self._expand_carry(tensors, state)
-        # a DENSE carry is donated to the delta dispatch below (the compact
-        # branch only donates its fresh expansion); mirror place()'s guard
-        # so a failure mid-delta forces the from-log rebuild instead of a
-        # later dispatch on a deleted buffer
+        # a DENSE carry is donated to the delta dispatch below; mirror
+        # place()'s guard so a failure mid-delta forces the from-log
+        # rebuild instead of a later dispatch on a deleted buffer
         self._state_dirty = True
         self.last_state = self._store_state(
             tensors, _apply_log_delta(statics, state, packed)

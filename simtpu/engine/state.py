@@ -1122,17 +1122,6 @@ expand_state = jax.jit(_expand_state_fn)
 # equals the dense f32 accumulate + truncating compress cast (pinned
 # bit-identical against the expand→apply→recompress route by
 # tests/test_state_deltas.py / tests/test_compact.py).
-#
-# SIMTPU_DELTA_DIRECT=0 falls the engines back to the round-trip route —
-# placements and carries are bit-identical either way; the switch exists for
-# A/B measurement (`make bench-scan`).
-
-
-def delta_direct_enabled() -> bool:
-    """Default for the engines' compact delta dispatch: SIMTPU_DELTA_DIRECT=0
-    re-routes compact preemption deltas through expand→apply→recompress
-    (1/unset = direct scatter)."""
-    return os.environ.get("SIMTPU_DELTA_DIRECT", "1") != "0"
 
 
 def node_dom_for(tensors, n: int) -> jnp.ndarray:

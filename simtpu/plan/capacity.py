@@ -989,12 +989,7 @@ class Applier:
         # rode SIMTPU_TRACE — that name now arms the span tracer instead.
         profile_dir = self.opts.profile or os.environ.get("SIMTPU_PROFILE", "")
         ctx = profile_capture(profile_dir) if profile_dir else contextlib.nullcontext()
-        from ..engine.scan import (
-            fused_cascade_enabled,
-            wave_enabled,
-            wave_heavy_enabled,
-        )
-        from ..engine.state import delta_direct_enabled
+        from ..engine.scan import wave_enabled
 
         search, bulk, mesh = _resolve_engines(self.opts, cluster, apps)
         metrics_before = REGISTRY.snapshot()
@@ -1128,15 +1123,9 @@ class Applier:
             # placements are bit-identical with it on or off, so this is
             # pure observability — acceptance rate and rollback volume
             "speculate": wave_enabled(),
-            # round-16 A/B switches, recorded so scripted consumers can
-            # detect the non-reference-exact fast paths from --json alone
-            # (ADVICE r5 #1): heavy wavefront drafting, the fused
-            # filter/score cascade, and the direct compact-delta apply —
-            # placements are bit-identical under every combination
-            "wave_heavy": wave_heavy_enabled(),
-            "fused_cascade": fused_cascade_enabled(),
+            # compact-carry delta applies that took the direct scatter, and
+            # the full-plane expand/compress passes the run still paid
             "delta_direct": {
-                "enabled": delta_direct_enabled(),
                 "applied": metrics.get("state.delta_direct", 0),
                 "expand": metrics.get("state.expand", 0),
                 "compress": metrics.get("state.compress", 0),

@@ -642,31 +642,16 @@ class TestEngineBlockRound16:
         assert plan.success, plan.message
         doc = json.loads(_plan_json(plan))
         eng = doc["engine"]
-        # the auto-selection record rides alongside the new switches
-        assert {"search", "auto_search", "auto_bulk"} <= set(eng)
+        # the auto-selection record rides alongside the engine counters
+        assert {"search", "auto_search", "auto_bulk", "speculate"} <= set(eng)
         assert eng["auto_search"] is False  # explicit search= above
-        # round-16 switches: booleans mirroring the env A/B levers
-        assert eng["wave_heavy"] is True
-        assert eng["fused_cascade"] is True
+        # the block reports what happened, not switches: the cascade form,
+        # the drafting rule and the compact delta route are fixed
+        assert not {"wave_heavy", "fused_cascade"} & set(eng)
         dd = eng["delta_direct"]
-        assert dd["enabled"] is True
+        assert set(dd) == {"applied", "expand", "compress"}
         for key in ("applied", "expand", "compress"):
             assert isinstance(dd[key], int) and dd[key] >= 0
         # the wavefront family carries the new hard-drafting counter
         assert "draft_hard" in eng["wavefront"]
         assert eng["wavefront"]["draft_hard"] >= 0
-
-    def test_switch_state_follows_env(self, monkeypatch):
-        import json
-
-        from simtpu.cli import _plan_json
-
-        monkeypatch.setenv("SIMTPU_WAVE_HEAVY", "0")
-        monkeypatch.setenv("SIMTPU_FUSED_CASCADE", "0")
-        monkeypatch.setenv("SIMTPU_DELTA_DIRECT", "0")
-        doc = json.loads(_plan_json(self._plan()))
-        eng = doc["engine"]
-        assert eng["wave_heavy"] is False
-        assert eng["fused_cascade"] is False
-        assert eng["delta_direct"]["enabled"] is False
-        assert eng["delta_direct"]["applied"] == 0

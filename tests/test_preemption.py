@@ -528,9 +528,9 @@ def test_preemption_under_compact_rides_direct_delta(monkeypatch):
     """ISSUE 16 tentpole: with a compact carry, the batched eviction delta
     and every restore of a rejected wave ride the DIRECT compact apply —
     the expand -> apply -> recompress round trip never runs on the hot
-    path (state.delta_direct > 0, state.expand/compress unchanged during
-    the replay), and the full simulation outcome (placements, evictions,
-    unscheduled set) is bit-identical to the SIMTPU_DELTA_DIRECT=0 path."""
+    path (state.delta_direct > 0), and the full simulation outcome
+    (placements, evictions, unscheduled set) is identical to a dense-carry
+    run (SIMTPU_COMPACT=0), whose deltas take the dense apply."""
     from simtpu.core.objects import AppResource
     from simtpu.obs.metrics import REGISTRY
     from simtpu.synth import make_deployment, make_node
@@ -586,28 +586,20 @@ def test_preemption_under_compact_rides_direct_delta(monkeypatch):
         )
         return delta, placements, evicted, unsched
 
-    monkeypatch.setenv("SIMTPU_DELTA_DIRECT", "1")
     d_direct, p_direct, e_direct, u_direct = run()
     assert e_direct, "scenario produced no preemptions — not exercising the path"
     assert d_direct["state.delta_direct"] > 0, d_direct
 
-    monkeypatch.setenv("SIMTPU_DELTA_DIRECT", "0")
-    d_ab, p_ab, e_ab, u_ab = run()
-    assert d_ab["state.delta_direct"] == 0, d_ab
-    # the placement dispatches themselves still expand/compress once per
-    # round (the kernels run dense) — identically on both paths; every
-    # EXTRA round trip in the A/B run is a delta replay the direct path
-    # eliminated.  (tests/test_state_deltas.py pins the exact zero around
-    # remove/restore in isolation.)
-    extra = d_ab["state.expand"] - d_direct["state.expand"]
-    assert extra >= d_direct["state.delta_direct"], (d_direct, d_ab)
-    assert d_ab["state.compress"] - d_direct["state.compress"] == extra, (
-        d_direct,
-        d_ab,
-    )
-    assert p_direct == p_ab
-    assert e_direct == e_ab
-    assert u_direct == u_ab
+    monkeypatch.setenv("SIMTPU_COMPACT", "0")
+    d_dense, p_dense, e_dense, u_dense = run()
+    # a dense carry is never compressed, so no delta can take the direct
+    # compact route and nothing expands
+    assert d_dense == {
+        "state.delta_direct": 0, "state.expand": 0, "state.compress": 0
+    }, d_dense
+    assert p_direct == p_dense
+    assert e_direct == e_dense
+    assert u_direct == u_dense
 
 
 # -- kube-scheduler's PreemptionBasic through `simtpu apply`'s default path --

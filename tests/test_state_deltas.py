@@ -517,17 +517,21 @@ class TestDirectCompactDelta:
         assert not np.array_equal(np.asarray(out.free), before.free)
         self._assert_equal(cstate, before, "input snapshot after apply")
 
-    def test_engine_preemption_path_skips_expand_recompress(
-        self, placed, monkeypatch
-    ):
+    def test_engine_preemption_path_skips_expand_recompress(self, placed):
         """Engine.remove_placements/restore_placements on a compact carry:
         the direct path fires (state.delta_direct +2), expand/recompress
         stay untouched, and the compact carry returns bit-identically —
-        then the SIMTPU_DELTA_DIRECT=0 round trip reproduces the same
-        carry, pinning the A/B bit-identity at the engine level."""
+        and the mid-eviction carry equals an explicit expand ->
+        apply_placement_deltas -> compress of the same packed deltas."""
         import jax
 
-        from simtpu.engine.state import CompactState
+        from simtpu.engine.state import (
+            CompactState,
+            compact_spec,
+            compress_state,
+            expand_state,
+            node_dom_small_for,
+        )
         from simtpu.obs.metrics import REGISTRY
 
         eng = placed.engine
@@ -537,17 +541,25 @@ class TestDirectCompactDelta:
         base_np = jax.tree_util.tree_map(lambda a: np.asarray(a).copy(), base)
         idx = list(range(0, len(eng.placed_node), 3))
 
-        def churn():
-            saved = eng.remove_placements(idx)
-            mid = jax.tree_util.tree_map(
-                lambda a: np.asarray(a).copy(), eng.last_state
-            )
-            eng.restore_placements(saved)
-            return mid
+        # the round-trip reference, built before the log is touched
+        tensors = placed.tensors
+        statics = statics_from(tensors, eng.sched_config)
+        spec = compact_spec(tensors)
+        nds = node_dom_small_for(tensors, base.free.shape[0])
+        packed = _pack(placed, _entries_of(eng, idx), -1.0)
+        mid_ref = compress_state(
+            spec.dev,
+            apply_placement_deltas(
+                statics, expand_state(spec.dev, base, nds), packed
+            ),
+        )
 
-        monkeypatch.setenv("SIMTPU_DELTA_DIRECT", "1")
         snap0 = REGISTRY.snapshot()
-        mid_direct = churn()
+        saved = eng.remove_placements(idx)
+        mid_direct = jax.tree_util.tree_map(
+            lambda a: np.asarray(a).copy(), eng.last_state
+        )
+        eng.restore_placements(saved)
         snap1 = REGISTRY.snapshot()
         assert isinstance(eng.last_state, CompactState)
         assert snap1.get("state.delta_direct", 0) - snap0.get(
@@ -558,22 +570,7 @@ class TestDirectCompactDelta:
                 f"{name} bumped on the direct preemption hot path"
             )
         self._assert_equal(eng.last_state, base, "direct carry round trip")
-
-        monkeypatch.setenv("SIMTPU_DELTA_DIRECT", "0")
-        snap2 = REGISTRY.snapshot()
-        mid_ab = churn()
-        snap3 = REGISTRY.snapshot()
-        assert snap3.get("state.delta_direct", 0) == snap2.get(
-            "state.delta_direct", 0
-        )
-        assert snap3.get("state.compress", 0) - snap2.get(
-            "state.compress", 0
-        ) == 2
-        self._assert_equal(eng.last_state, base, "round-trip carry")
-        for name in base._fields:
-            assert np.array_equal(
-                getattr(mid_direct, name), getattr(mid_ab, name)
-            ), f"mid-eviction carry differs between paths: {name}"
+        self._assert_equal(mid_direct, mid_ref, "mid-eviction carry")
         # the log and carry are back to the fixture's original state for
         # the tests that share this module-scoped fixture
         self._assert_equal(eng.last_state, base_np, "fixture restored")

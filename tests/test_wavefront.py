@@ -418,7 +418,7 @@ class TestFullScaleSpotCheck:
 
 class TestHeavyDrafting:
     """ISSUE 16 tentpole: storage/GPU/ports/volume pods — excluded from
-    drafting entirely before SIMTPU_WAVE_HEAVY — now ride the HARD
+    drafting entirely by the pre-round-16 lean rule — ride the HARD
     verifier with the extra resource stages (ports conflicts, LVM/device
     allocation, GPU-share fitting) recomputed inside the verify scan.
     Placements stay bit-identical to the serial scan, the accept rate on
@@ -426,9 +426,8 @@ class TestHeavyDrafting:
 
     def _all_heavy_problem(self):
         """Every pod carries a heavy feature (LVM, exclusive device, GPU
-        share, or hostPort): with SIMTPU_WAVE_HEAVY=0 the wavefront drafts
-        NOTHING here, so any accept under heavy=1 is attributable to the
-        new path."""
+        share, or hostPort): the lean rule drafts NOTHING here, so every
+        drafted pod must ride the hard verifier."""
         from simtpu.core.objects import AppResource, ResourceTypes
 
         cluster = synth_cluster(
@@ -444,28 +443,23 @@ class TestHeavyDrafting:
         ]
         return cluster, [AppResource(name="heavy", resource=res)]
 
-    def test_all_heavy_mix_accepts_where_legacy_skips(self, monkeypatch):
+    def test_all_heavy_mix_accepts_where_legacy_skips(self):
         cluster, apps = self._all_heavy_problem()
         serial = _place(cluster, apps, speculate=False)
-
-        monkeypatch.setenv("SIMTPU_WAVE_HEAVY", "0")
         before = wave_counts()
-        legacy = _place(cluster, apps, speculate=True)
-        mid = wave_counts()
-        _assert_identical(serial, legacy)
-        assert mid["pods"] == before["pods"], (
-            "legacy mask drafted a heavy pod — the A/B control is broken"
-        )
-
-        monkeypatch.setenv("SIMTPU_WAVE_HEAVY", "1")
         wave = _place(cluster, apps, speculate=True)
         after = wave_counts()
         _assert_identical(serial, wave)
-        drafted = after["pods"] - mid["pods"]
-        accepted = after["accepted"] - mid["accepted"]
-        hard = after["draft_hard"] - mid["draft_hard"]
+        drafted = after["pods"] - before["pods"]
+        accepted = after["accepted"] - before["accepted"]
+        hard = after["draft_hard"] - before["draft_hard"]
         assert drafted > 0, "no heavy pod was drafted"
         assert hard > 0, "heavy pods must ride the hard verifier"
+        # no pod of this mix is LEAN, so no wavefront may take the lean
+        # verifier: every drafted pod is a hard-verifier pod
+        assert hard == drafted, (
+            f"{drafted - hard} of {drafted} all-heavy pods drafted as lean"
+        )
         assert accepted > 0, (
             f"wavefront_accept_rate is 0 on the all-heavy mix "
             f"({drafted} drafted)"
